@@ -22,8 +22,9 @@ holds the acceptance properties, +1.0 for each violation:
   float-path schedule-invariance gate lives in ``serve traffic`` and the
   tier-1 tests);
 * the paged decode step is bit-exact with the contiguous
-  ``model_lib.decode_step`` reference at fp32
-  (``repro.serving.paged_vs_contiguous_probe`` returns 0.0).
+  ``model_lib.decode_step`` reference at fp32, and the engine's bucketed
+  prefill within ``PREFILL_LOGIT_TOL`` of the contiguous prefill
+  (``repro.serving.paged_vs_contiguous_probe``).
 """
 
 from __future__ import annotations
@@ -40,9 +41,11 @@ NUM_UNITS = 64
 BITS = 4
 
 
-def _markdown(tcfg, reports, probe: float) -> str:
+def _markdown(tcfg, reports, probe) -> str:
     rc, rs = reports["continuous"], reports["static"]
     gain = rc.throughput_tok_per_step / max(rs.throughput_tok_per_step, 1e-30)
+    decode = ("bit-exact" if probe.decode == 0.0
+              else f"max |diff| {probe.decode:.3e}")
     lines = [
         "# Serving under traffic: continuous vs static batching",
         "",
@@ -69,8 +72,9 @@ def _markdown(tcfg, reports, probe: float) -> str:
         f"{rc.latency_p99:.0f} vs {rs.latency_p99:.0f} steps, "
         f"{rc.energy_per_token_uj:.4f} vs {rs.energy_per_token_uj:.4f} "
         "uJ/token on the same trace.",
-        f"Paged decode vs contiguous `decode_step` (fp32): "
-        f"{'bit-exact' if probe == 0.0 else f'max |diff| {probe:.3e}'}.",
+        f"Paged decode vs contiguous `decode_step` (fp32): {decode}; "
+        f"bucketed prefill vs contiguous prefill: max |diff| "
+        f"{probe.prefill:.3e}.",
         "",
     ]
     return "\n".join(lines)
@@ -82,7 +86,8 @@ def serving(out_dir: str | None = None):
 
     from repro import configs
     from repro.models import model as model_lib
-    from repro.serving import (ServingEngine, TrafficConfig, generate_trace,
+    from repro.serving import (PREFILL_LOGIT_TOL, ServingEngine,
+                               TrafficConfig, generate_trace,
                                paged_vs_contiguous_probe)
 
     out_dir = out_dir or os.environ.get("SERVING_OUT", "reports")
@@ -111,7 +116,8 @@ def serving(out_dir: str | None = None):
             "continuous": rc.to_dict(), "static": rs.to_dict(),
             "throughput_gain": gain, "all_completed": complete,
             "token_streams_identical": same_tokens,
-            "paged_probe_max_abs_diff": probe,
+            "paged_probe_max_abs_diff": probe.decode,
+            "prefill_probe_max_abs_diff": probe.prefill,
         }, fh, indent=2)
     md_path = os.path.join(out_dir, "serving.md")
     with open(md_path, "w") as fh:
@@ -133,7 +139,8 @@ def serving(out_dir: str | None = None):
         ("continuous_vs_static_throughput", f"{gain:.2f}x", None),
         ("all_requests_completed", str(complete), None),
         ("token_streams_identical", str(same_tokens), None),
-        ("paged_vs_contiguous_max_abs_diff", f"{probe:.3e}", None),
+        ("paged_vs_contiguous_max_abs_diff", f"{probe.decode:.3e}", None),
+        ("prefill_vs_contiguous_max_abs_diff", f"{probe.prefill:.3e}", None),
         ("json", json_path, None),
         ("markdown", md_path, None),
     ]
@@ -142,6 +149,8 @@ def serving(out_dir: str | None = None):
         err += 1.0  # continuous batching must not lose to static batching
     if not complete:
         err += 1.0  # every request must be served to completion
-    if probe != 0.0:
+    if probe.decode != 0.0:
         err += 1.0  # paged decode must match the contiguous path bit-for-bit
+    if probe.prefill > PREFILL_LOGIT_TOL:
+        err += 1.0  # bucketed prefill: fp32 reassociation only
     return rows, err

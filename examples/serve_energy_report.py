@@ -35,7 +35,7 @@ def main():
 
     cfg = configs.get_smoke_config(args.arch)
     mesh = single_device_mesh()
-    with mesh:
+    with jax.set_mesh(mesh):
         params = M.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (args.batch, 16)),

@@ -6,7 +6,7 @@ them fed by a partitioned model.  This module composes any resolved
 tensor-parallel grid that is simultaneously
 
 * **executable** — :meth:`GridBackend.execute` runs the contraction under
-  ``repro.compat.shard_map`` on a real ``launch/mesh`` device mesh: the
+  ``jax.shard_map`` on a real ``launch/mesh`` device mesh: the
   contraction dim K is split over the ``gx`` axis (per-chip partial sums
   reduced with ``lax.psum``), the output columns over ``gy``.  Partial sums
   are exact (int32 for the exact designs; uGEMM's float counts are exact
@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.backends.base import GemmBackend
 from repro.backends.plan import SCHEMA as PLAN_SCHEMA
 from repro.backends.plan import BackendPlan
@@ -188,7 +187,7 @@ class GridBackend(GemmBackend):
 
         Shapes as :meth:`GemmBackend.execute`.  2-D operands are zero-padded
         to the grid (zero codes contribute exact zeros on every design), K
-        is split over ``gx`` and N over ``gy`` under ``compat.shard_map`` on
+        is split over ``gx`` and N over ``gy`` under ``jax.shard_map`` on
         the :func:`grid_mesh` devices, and the per-chip partial sums reduce
         with ``lax.psum`` — int32 (exact designs) or exact-integer float32
         (uGEMM), so the reduction order cannot change the result.  Batched
@@ -220,9 +219,9 @@ class GridBackend(GemmBackend):
             part = exact_fn(a_sub, b_sub, bits)
             return jax.lax.psum(part, "gx") if reduce_k else part
 
-        fn = compat.shard_map(node, mesh=grid_mesh(x_parts, y_parts),
-                              in_specs=(P(None, "gx"), P("gx", "gy")),
-                              out_specs=P(None, "gy"), check_vma=False)
+        fn = jax.shard_map(node, mesh=grid_mesh(x_parts, y_parts),
+                           in_specs=(P(None, "gx"), P("gx", "gy")),
+                           out_specs=P(None, "gy"), check_vma=False)
         return fn(ap, bp)[:, :n]
 
     def stream(self, a: jax.Array, b: jax.Array):

@@ -91,19 +91,21 @@ def bit_sparsity_blockmax(q: jax.Array, bits: int, block: int = 32) -> jax.Array
     all-zero blocks are masked out of the mean.
     """
     L = 2 ** (bits - 1)
-    x = jnp.abs(q.astype(jnp.float32))
-    if x.ndim == 1:
-        x = x[None, :]
-    else:
-        x = x.reshape(-1, x.shape[-1])
+    x = q[None, :] if q.ndim == 1 else q.reshape(-1, q.shape[-1])
     r, c = x.shape
-    pr, pc = (-r) % block, (-c) % block
-    x = jnp.pad(x, ((0, pr), (0, pc)))
-    x = x.reshape(x.shape[0] // block, block, x.shape[1] // block, block)
-    blk_max = jnp.max(x, axis=(1, 3))
-    # Padded all-zero blocks would bias the mean down; mask them out.
     nr, nc = (r + block - 1) // block, (c + block - 1) // block
-    blk_max = blk_max[:nr, :nc]
+    x = jnp.pad(x, ((0, nr * block - r), (0, nc * block - c)))
+
+    def band_max(i):
+        # one (block, C) row band at a time: the block maxima of a stacked
+        # weight never need a reshaped float copy of the whole tensor
+        band = jax.lax.dynamic_slice_in_dim(x, i * block, block, axis=0)
+        band = jnp.abs(band.astype(jnp.int32)).reshape(block, nc, block)
+        return jnp.max(band, axis=(0, 2))
+
+    # bands past the last real row are never formed, so padded all-zero
+    # blocks cannot bias the mean down
+    blk_max = jax.lax.map(band_max, jnp.arange(nr)).astype(jnp.float32)
     return 1.0 - jnp.mean(blk_max) / L
 
 

@@ -27,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "DEFAULT_BQ", "DEFAULT_BK"]
 
@@ -120,11 +121,7 @@ def _fwd(q, k, v, *, causal: bool, bq: int, bk: int, kv_len: int | None,
 
 
 def _vmem(shape, dtype):
-    try:  # pragma: no cover - TPU path
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, dtype)
+    return pltpu.VMEM(shape, dtype)
 
 
 # ---------------------------------------------------------------------------

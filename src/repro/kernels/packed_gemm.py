@@ -60,10 +60,10 @@ def packed_gemm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)              # (bm, bk)
-    w = unpack_words(w_ref[...], bits)            # (bk, bn) int32 codes
+    # int8 x int8 -> int32 on the MXU: w-bit codes (w <= 8) fit int8
+    w = unpack_words(w_ref[...], bits).astype(jnp.int8)   # (bk, bn) codes
     acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
+        x_ref[...], w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)

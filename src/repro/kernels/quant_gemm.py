@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["quant_gemm_kernel", "quant_gemm", "unpack_values", "DEFAULT_BLOCK"]
 
@@ -141,12 +142,5 @@ def quant_gemm(x: jax.Array, w_packed: jax.Array, scales: jax.Array | None = Non
 
 
 def _acc_scratch(bm: int, bn: int):
-    # pltpu.VMEM when the TPU plugin imports (it also drives interpret mode on
-    # CPU); otherwise a backend-neutral MemoryRef.  MemorySpace members are
-    # plain enum values, not scratch-shape constructors — the previous
-    # ``pl.MemorySpace.ANY((bm, bn), ...)`` fallback raised TypeError.
-    try:  # pragma: no cover - TPU path
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM((bm, bn), jnp.int32)
-    except Exception:  # pragma: no cover
-        return pl.MemoryRef((bm, bn), jnp.int32, pl.MemorySpace.ANY)
+    """int32 VMEM accumulator tile (interpret mode emulates it on CPU)."""
+    return pltpu.VMEM((bm, bn), jnp.int32)

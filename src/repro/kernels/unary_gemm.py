@@ -92,7 +92,7 @@ def tub_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, bits: int, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     a = a_ref[...].astype(jnp.int32)                 # (bm, bk)
-    b = b_ref[...].astype(jnp.int32)                 # (bk, bn)
+    b = b_ref[...]                                   # (bk, bn) int8
     mag = jnp.abs(a)
     sgn = jnp.sign(a)
     v1, v0 = mag // 2, mag % 2
@@ -101,9 +101,11 @@ def tub_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, bits: int, n_k: int):
     def slot(t, acc):
         two_gate = 2 * (t < v1).astype(jnp.int32)    # weight-2 slots
         one_gate = jnp.where(t == 0, v0, 0)          # odd bit on slot 0
-        pulses = (two_gate + one_gate) * sgn         # (bm, bk)
+        pulses = (two_gate + one_gate) * sgn         # (bm, bk), |p| <= 3
+        # int8 x int8 -> int32 is the MXU's native integer path (Mosaic
+        # refuses an int32 x int32 dot); the pulses and B codes both fit.
         return acc + jax.lax.dot_general(
-            pulses, b, (((1,), (0,)), ((), ())),
+            pulses.astype(jnp.int8), b, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
 
     acc_ref[...] += jax.lax.fori_loop(0, n_slots, slot,
@@ -170,15 +172,15 @@ def tu_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, bits: int, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     a = a_ref[...].astype(jnp.int32)                 # (bm, bk)
-    b = b_ref[...].astype(jnp.int32)                 # (bk, bn)
+    b = b_ref[...]                                   # (bk, bn) int8
     mag = jnp.abs(a)
     sgn = jnp.sign(a)
     n_slots = 2 ** (bits - 1)
 
     def slot(i, acc):
-        pulses = (i < mag).astype(jnp.int32) * sgn   # (bm, bk)
+        pulses = (i < mag).astype(jnp.int32) * sgn   # (bm, bk), |p| <= 1
         return acc + jax.lax.dot_general(
-            pulses, b, (((1,), (0,)), ((), ())),
+            pulses.astype(jnp.int8), b, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
 
     acc_ref[...] += jax.lax.fori_loop(0, n_slots, slot,

@@ -106,7 +106,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str | None,
            "mesh": "2x16x16" if multi_pod else "16x16", "chips": chips,
            "ep_impl": ep_impl or (cfg.moe.ep_impl if cfg.is_moe else None)}
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = lower_cell(cfg, shape_name, mesh)
         rec["lower_s"] = round(time.time() - t0, 1)
         t1 = time.time()

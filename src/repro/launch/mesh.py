@@ -9,6 +9,7 @@ and benchmarks must keep seeing the single real CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "make_grid_mesh",
            "single_device_mesh"]
@@ -34,7 +35,8 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     if len(devs) < n:
         raise RuntimeError(f"need {n} devices, have {len(devs)} "
                            "(dry-run must set xla_force_host_platform_device_count)")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_grid_mesh(units_x: int, units_y: int):
@@ -56,5 +58,5 @@ def make_grid_mesh(units_x: int, units_y: int):
 def single_device_mesh(model_axis: bool = True):
     """Trivial mesh for CPU tests: same axis names, size-1 axes."""
     if model_axis:
-        return jax.make_mesh((1, 1), ("data", "model"))
-    return jax.make_mesh((1,), ("data",))
+        return make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1,), ("data",))

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 __all__ = ["pipeline_apply", "split_stages"]
 
@@ -76,7 +75,7 @@ def pipeline_apply(stage_fn: Callable, staged_params, x, mesh,
         # outputs live on the last pod only; sum-replicate across stages
         return lax.psum(outs, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -58,7 +58,7 @@ def train(cfg, mesh, loop: TrainLoopConfig):
     data = make_pipeline(data_cfg)
 
     mgr = CheckpointManager(loop.ckpt_dir, keep=loop.keep) if loop.ckpt_dir else None
-    with mesh:
+    with jax.set_mesh(mesh):
         state = steps_lib.init_train_state(cfg, opt_cfg,
                                            jax.random.PRNGKey(loop.seed))
         start = 0

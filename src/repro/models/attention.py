@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import shard_map
 from repro.models import rope as rope_lib
 from repro.models.common import ParamDef, dense, rmsnorm, shard
 from repro.models.config import ModelConfig
@@ -211,8 +210,8 @@ def _repeat_kv(kv: jax.Array, h: int) -> jax.Array:
 
 
 def _current_mesh():
-    env = jax.interpreters.pxla.thread_resources.env
-    return None if env.physical_mesh.empty else env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _sharded_decode_attention(q, kc, vc, h: int, *, q_offset, kv_valid_len,
@@ -258,7 +257,7 @@ def _sharded_decode_attention(q, kc, vc, h: int, *, q_offset, kv_valid_len,
         out = ctx_g / jnp.maximum(l_g[..., None], 1e-30).astype(ctx_g.dtype)
         return out.transpose(0, 2, 1, 3)                     # (B,Sq,H,hd)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(baxes), P(baxes, "model"), P(baxes, "model"), P(), P()),
         out_specs=P(baxes),
@@ -457,7 +456,7 @@ def _mla_sharded_decode(params, q_nope, q_rope, ckv, krope, cfg, *,
         out = ctx_g / jnp.maximum(l_g[..., None], 1e-30).astype(ctx_g.dtype)
         return out.transpose(0, 2, 1, 3)                 # (B,Sq,H,rank)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(baxes), P(baxes), P(baxes, "model"), P(baxes, "model"),
                   P(), P()),

@@ -113,8 +113,7 @@ def shardable_batch_axes(mesh, batch_size: int,
 
 
 def _mesh_axes_present() -> tuple[str, ...]:
-    env = jax.interpreters.pxla.thread_resources.env
-    mesh = env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     return () if mesh.empty else tuple(mesh.axis_names)
 
 
@@ -161,9 +160,9 @@ def logical_to_pspec(logical: tuple[str | None, ...],
 
 def shard(x: jax.Array, *logical: str | None,
           rules: dict[str, object] | None = None) -> jax.Array:
-    """Constrain ``x``'s sharding by logical axis names (no-op without mesh)."""
-    env = jax.interpreters.pxla.thread_resources.env
-    mesh = env.physical_mesh
+    """Constrain ``x``'s sharding by logical axis names (no-op outside a
+    ``jax.set_mesh`` context)."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or not mesh.axis_names:
         return x
     rules = DEFAULT_RULES if rules is None else rules

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.common import ParamDef, shard
 from repro.models.config import ModelConfig
 from repro.backends.runtime import site_scope
@@ -117,8 +116,7 @@ def _aux_loss(probs, topk_idx, cfg: ModelConfig):
 
 
 def _current_mesh():
-    env = jax.interpreters.pxla.thread_resources.env
-    mesh = env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh
 
 
@@ -174,7 +172,7 @@ def _moe_ep_psum(params, x_flat, cfg: ModelConfig, mesh, scoring):
         aux = _aux_loss(probs, topk_idx, cfg)   # identical on every rank
         return out, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(), P("model"), P("model"), P("model"), P(baxes)),
         out_specs=(P(baxes), P()),
@@ -245,7 +243,7 @@ def _moe_ep_a2a(params, x_flat, cfg: ModelConfig, mesh, scoring):
         aux = lax.psum(_aux_loss(probs, topk_idx, cfg), "model") / n_model
         return out, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(), P("model"), P("model"), P("model"), P(baxes)),
         out_specs=(P(baxes), P()),

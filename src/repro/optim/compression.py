@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 __all__ = ["quantize_int8", "dequantize_int8", "compress_with_error_feedback",
            "int8_psum"]
@@ -76,7 +75,7 @@ def int8_psum(grads, mesh, axis: str = "data"):
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     specs = tuple(P() for _ in leaves)
-    fn = shard_map(block, mesh=mesh, in_specs=specs, out_specs=specs,
+    fn = jax.shard_map(block, mesh=mesh, in_specs=specs, out_specs=specs,
                        check_vma=False)
     out = fn(*leaves)
     return jax.tree_util.tree_unflatten(treedef, out)

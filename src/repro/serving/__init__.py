@@ -14,8 +14,9 @@ allocator invariants, the paged-vs-contiguous bit-exactness, and the
 seed-determinism of the whole loop.
 """
 
-from repro.serving.engine import (FUSED_LOGIT_TOL, ServingEngine,
-                                  ServingReport, fused_vs_gather_probe,
+from repro.serving.engine import (FUSED_LOGIT_TOL, PREFILL_LOGIT_TOL,
+                                  PagedProbe, ServingEngine, ServingReport,
+                                  fused_vs_gather_probe,
                                   paged_vs_contiguous_probe)
 from repro.serving.paged_kv import OutOfPages, PageAllocator, PagedKVCache
 from repro.serving.scheduler import (ContinuousBatchingScheduler, Request,
@@ -24,8 +25,9 @@ from repro.serving.scheduler import (ContinuousBatchingScheduler, Request,
 from repro.serving.traffic import TrafficConfig, TrafficRequest, generate_trace
 
 __all__ = [
-    "ServingEngine", "ServingReport", "paged_vs_contiguous_probe",
-    "fused_vs_gather_probe", "FUSED_LOGIT_TOL",
+    "ServingEngine", "ServingReport", "PagedProbe",
+    "paged_vs_contiguous_probe", "fused_vs_gather_probe", "FUSED_LOGIT_TOL",
+    "PREFILL_LOGIT_TOL",
     "OutOfPages", "PageAllocator", "PagedKVCache",
     "ContinuousBatchingScheduler", "StaticBatchingScheduler",
     "Request", "RequestState", "make_scheduler",

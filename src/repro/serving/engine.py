@@ -34,14 +34,18 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from collections import OrderedDict, deque
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import backends as backends_lib
 from repro.backends.runtime import site_scope
+from repro.core import packing
 from repro.kernels import paged_attention as paged_lib
 from repro.kernels import paged_attention_fused as fused_lib
 from repro.launch.mesh import make_grid_mesh, single_device_mesh
@@ -57,14 +61,20 @@ from repro.serving.scheduler import (Request, RequestState, _SchedulerBase,
                                      make_scheduler)
 from repro.serving.traffic import TrafficRequest
 
-__all__ = ["ServingEngine", "ServingReport", "paged_vs_contiguous_probe",
-           "fused_vs_gather_probe", "FUSED_LOGIT_TOL"]
+__all__ = ["ServingEngine", "ServingReport", "PagedProbe",
+           "paged_vs_contiguous_probe", "fused_vs_gather_probe",
+           "FUSED_LOGIT_TOL", "PREFILL_LOGIT_TOL"]
 
 #: gated max |Δlogit| between the fused online-softmax decode path and the
 #: bit-exact gather oracle on the fp32 smoke probe — online softmax
-#: re-associates the reduction, so exact equality is not the contract; the
-#: sampled token streams still must match exactly on the seeded traces.
+#: re-associates the reduction, so exact equality is not the contract.
 FUSED_LOGIT_TOL = 1e-4
+#: gated max |Δ| between the engine's bucketed prefill and the contiguous
+#: ``prefill_step`` at fp32 (``paged_vs_contiguous_probe``): the two are
+#: XLA programs of different shapes (padded bucket and batch vs the bare
+#: prompts), so fp32 reassociation is their only licensed difference —
+#: ~1e-6 on the CPU smoke configs.
+PREFILL_LOGIT_TOL = 1e-4
 
 #: shared, bounded cache of jitted prefill callables.  Keyed on everything
 #: the *trace* depends on — (cfg, backend/plan scope, grid, activation-scale
@@ -127,19 +137,69 @@ def _bucket(n: int, floor: int = 4) -> int:
     return b
 
 
+def _grid_shardings(params, mesh):
+    """Shardings that spread a grid engine's weights over its mesh.
+
+    Each weight matrix is split the way the grid's GEMMs split it: the
+    contraction rows over ``gx`` (a packed store's K-bands, a float leaf's
+    first axis after the layer stack) and the output columns over ``gy``,
+    wherever the sizes divide.  Vectors, scales and the embedding table are
+    replicated.
+    """
+    gx, gy = mesh.shape["gx"], mesh.shape["gy"]
+
+    def named(*spec):
+        return NamedSharding(mesh, P(*spec))
+
+    def split(shape, k_axis):
+        spec = [None] * len(shape)
+        if k_axis is not None and shape[k_axis] % gx == 0:
+            spec[k_axis] = "gx"
+        if shape[-1] % gy == 0:
+            spec[-1] = "gy"
+        return named(*spec)
+
+    def place(path, leaf):
+        if packing.is_packed(leaf):
+            words = (split(leaf.packed.shape, -3) if leaf.grid_x > 1
+                     else split(leaf.packed.shape, None))
+            return dataclasses.replace(leaf, packed=words, scale=named())
+        top = getattr(path[0], "key", None)
+        if top == "layers" and leaf.ndim >= 3:
+            return split(leaf.shape, 1)
+        if top == "lm_head":
+            return split(leaf.shape, 0)
+        return named()
+
+    return jax.tree_util.tree_map_with_path(place, params,
+                                            is_leaf=packing.is_packed)
+
+
+class PagedProbe(NamedTuple):
+    """What :func:`paged_vs_contiguous_probe` measured at fp32."""
+    prefill: float   # max |Δ| of last-position logits and KV, engine vs
+    #                  the contiguous prefill_step (<= PREFILL_LOGIT_TOL)
+    decode: float    # max |Δlogit|, paged vs contiguous decode from the
+    #                  same KV (0.0 = bit-exact)
+
+
 def paged_vs_contiguous_probe(cfg: ModelConfig, params, *, batch: int = 2,
                               prompt_len: int = 5, steps: int = 3,
-                              page_size: int = 4) -> float:
-    """Max |paged - contiguous| decode logit difference at fp32 (0.0 = exact).
+                              page_size: int = 4) -> PagedProbe:
+    """The engine's prefill and paged decode against the contiguous path.
 
-    Runs ``steps`` aligned decode steps (every slot at the same position, so
-    ``model_lib.decode_step``'s scalar ``cache_pos`` applies) through both
-    the engine's paged scatter/gather step and the contiguous
-    ``dynamic_update_slice`` cache path, greedy-feeding each path its own
-    argmax token, and returns the worst absolute logit difference seen.
-    ``page_size`` deliberately defaults to a non-divisor of typical prompt
-    lengths so partially filled pages are exercised.  The serving CLI, the
-    serving benchmark and the tier-1 tests all gate on this returning 0.0.
+    Prefill: the engine's bucketed admission prefill (padded to its
+    ``(max_batch, bucket)`` shape) against ``steps_lib.make_prefill_step``
+    on the unpadded prompts — two XLA programs of different shapes, so the
+    contract is ``<= PREFILL_LOGIT_TOL`` over the last-position logits and
+    every K/V row, not equality.  Decode: ``steps`` aligned decode steps
+    (every slot at the same position, so ``model_lib.decode_step``'s scalar
+    ``cache_pos`` applies) through both the engine's paged scatter/gather
+    step and the contiguous ``dynamic_update_slice`` cache path, both
+    seeded with the engine's KV and greedy-feeding each path its own argmax
+    token; the tier-1 tests and the serving benchmark hold this to 0.0 on a
+    CPU.  ``page_size`` deliberately defaults to a non-divisor of typical
+    prompt lengths so partially filled pages are exercised.
     """
     from repro.launch import steps as steps_lib  # avoid cycle at import time
 
@@ -158,21 +218,35 @@ def paged_vs_contiguous_probe(cfg: ModelConfig, params, *, batch: int = 2,
         page_size=page_size, max_seq_len=engine.max_seq_len)
     btables = np.zeros((batch, cache.max_blocks), np.int32)
     worst = 0.0
-    with engine._mesh as mesh:
+    mesh = engine._mesh
+    with jax.set_mesh(mesh):
         prefill_step = steps_lib.make_prefill_step(cfg, mesh,
                                                    params_like=params)
         decode_step = steps_lib.make_decode_step(cfg, mesh,
                                                  params_like=params)
+        ref_logits, ref_caches = prefill_step(
+            params, {"tokens": jnp.asarray(prompts)},
+            model_lib.init_caches(cfg, batch, total, dtype=jnp.float32))
         caches = model_lib.init_caches(cfg, batch, total, dtype=jnp.float32)
-        logits, caches = prefill_step(params, {"tokens": jnp.asarray(prompts)},
-                                      caches)
-        tok_ref = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        for i in range(batch):
-            _, k_l, v_l = engine._prefill(jnp.asarray(prompts[i: i + 1]))
+        rows = engine._prefill_rows(list(prompts))
+        prefill_diff = 0.0
+        for i, (last, k_l, v_l) in enumerate(rows):
+            prefill_diff = max(
+                prefill_diff,
+                float(jnp.max(jnp.abs(last - ref_logits[i, -1]))),
+                float(jnp.max(jnp.abs(
+                    k_l - ref_caches["attn"]["k"][:, i, :prompt_len]))),
+                float(jnp.max(jnp.abs(
+                    v_l - ref_caches["attn"]["v"][:, i, :prompt_len]))))
+            caches["attn"]["k"] = caches["attn"]["k"].at[:, i, :prompt_len] \
+                .set(k_l)
+            caches["attn"]["v"] = caches["attn"]["v"].at[:, i, :prompt_len] \
+                .set(v_l)
             cache.allocate(i, total)
-            cache.write_prefill(i, k_l[:, 0, :prompt_len],
-                                v_l[:, 0, :prompt_len])
+            cache.write_prefill(i, k_l, v_l)
             btables[i] = cache.block_table_row(i)
+        tok_ref = jnp.stack([jnp.argmax(r[0]) for r in rows])[:, None] \
+            .astype(jnp.int32)
         tok_paged = tok_ref
         for i in range(steps):
             pos = prompt_len + i
@@ -187,7 +261,7 @@ def paged_vs_contiguous_probe(cfg: ModelConfig, params, *, batch: int = 2,
                 lg[:, 0] - ref_logits[:, 0]))))
             tok_ref = jnp.argmax(ref_logits[:, -1:], axis=-1).astype(jnp.int32)
             tok_paged = jnp.argmax(lg[:, :1], axis=-1).astype(jnp.int32)
-    return worst
+    return PagedProbe(prefill=prefill_diff, decode=worst)
 
 
 def fused_vs_gather_probe(cfg, params, *, batch: int = 2, prompt_len: int = 5,
@@ -220,12 +294,10 @@ def fused_vs_gather_probe(cfg, params, *, batch: int = 2, prompt_len: int = 5,
         page_size=page_size, max_seq_len=fused.max_seq_len)
     btables = np.zeros((batch, cache.max_blocks), np.int32)
     worst = 0.0
-    with fused._mesh:
-        for i in range(batch):
-            _, k_l, v_l = gather._prefill(jnp.asarray(prompts[i: i + 1]))
+    with jax.set_mesh(fused._mesh):
+        for i, (_, k_l, v_l) in enumerate(gather._prefill_rows(list(prompts))):
             cache.allocate(i, total)
-            cache.write_prefill(i, k_l[:, 0, :prompt_len],
-                                v_l[:, 0, :prompt_len])
+            cache.write_prefill(i, k_l, v_l)
             btables[i] = cache.block_table_row(i)
         tok = jnp.asarray(prompts[:, -1:])  # any aligned token works
         for i in range(steps):
@@ -308,6 +380,10 @@ class ServingEngine:
                                  and jax.default_backend() != "tpu")
         self.batched_prefill = batched_prefill
         self._mesh = make_grid_mesh(*grid) if grid else single_device_mesh()
+        if self._mesh.size > 1:
+            self._exec_params = jax.device_put(
+                self._exec_params, _grid_shardings(self._exec_params,
+                                                   self._mesh))
         self._decode = jax.jit(self._decode_fn)
 
     # -- jitted model steps ---------------------------------------------------
@@ -344,10 +420,18 @@ class ServingEngine:
                     pv = paged_lib.write_kv_token(pv, block_tables, lengths,
                                                   v[:, 0], self.page_size)
                     if self.attention == "fused":
-                        out = fused_lib.fused_paged_decode_attention(
-                            q, pk, pv, block_tables, lengths + 1,
+                        walk = functools.partial(
+                            fused_lib.fused_paged_decode_attention,
                             num_heads=cfg.num_heads, impl=self.attention_impl,
                             interpret=self._fused_interpret)
+                        if self._mesh.size > 1:
+                            # XLA cannot partition a Mosaic kernel: on a
+                            # PE-grid mesh every chip walks the whole
+                            # (replicated) pools
+                            walk = jax.shard_map(
+                                walk, mesh=self._mesh, in_specs=(P(),) * 5,
+                                out_specs=P(), check_vma=False)
+                        out = walk(q, pk, pv, block_tables, lengths + 1)
                     else:
                         out = paged_lib.paged_decode_attention(
                             q, pk, pv, block_tables, lengths + 1,
@@ -399,6 +483,33 @@ class ServingEngine:
 
         fn = _prefill_cache_get(self._prefill_cache_key(s), make)
         return fn(self._exec_params, tokens)
+
+    def _prefill_rows(self, prompts) -> list[tuple]:
+        """Per prompt, (last-logits row, K rows, V rows) of its prefill.
+
+        Prompts sharing a ``_bucket(len)`` run in one call, and every call
+        is padded with dummy rows to ``(max_batch, bucket)``.  XLA compiles
+        each batch size differently and on a TPU the rounding follows, but
+        at one fixed shape a row's result depends on neither its position
+        nor its neighbours (causal attention, row-wise matmuls), so a
+        request's KV and first token are a function of its own prompt
+        whichever requests it was admitted with.
+        """
+        groups: dict[int, list[int]] = {}
+        for i, p in enumerate(prompts):
+            groups.setdefault(_bucket(len(p)), []).append(i)
+        out: list = [None] * len(prompts)
+        for width, idx in groups.items():
+            for lo in range(0, len(idx), self.max_batch):
+                chunk = idx[lo: lo + self.max_batch]
+                padded = np.zeros((self.max_batch, width), np.int32)
+                for r, i in enumerate(chunk):
+                    padded[r, : len(prompts[i])] = prompts[i]
+                logits, k_l, v_l = self._prefill(jnp.asarray(padded))
+                for r, i in enumerate(chunk):
+                    p = len(prompts[i])
+                    out[i] = (logits[r, p - 1], k_l[:, r, :p], v_l[:, r, :p])
+        return out
 
     # -- host-side serving loop -----------------------------------------------
 
@@ -488,35 +599,6 @@ class ServingEngine:
             finished.append(req)
             events.append((at, "evict", req.req_id))
 
-        def prefill_admissions(reqs: list[Request]) -> dict:
-            """req_id -> (last-logits row, K rows, V rows) for this step's
-            admissions — one jitted prefill call per ``_bucket(prompt_len)``
-            group (or per request when ``batched_prefill=False``).
-
-            Causal attention makes each padded prompt's valid prefix
-            independent of both the tail padding and the other prompts in
-            the batch, so grouping changes nothing the tests can see —
-            ``tests/test_paged_fused.py`` pins the token streams identical
-            to the per-request path.
-            """
-            groups: dict[object, list] = {}
-            for req in reqs:
-                key = (_bucket(req.spec.prompt_len) if self.batched_prefill
-                       else ("solo", req.spec.req_id))
-                groups.setdefault(key, []).append(req.spec)
-            out = {}
-            for specs in groups.values():
-                width = _bucket(max(s.prompt_len for s in specs))
-                padded = np.zeros((len(specs), width), np.int32)
-                for i, spec in enumerate(specs):
-                    padded[i, : spec.prompt_len] = self.prompt_tokens(spec)
-                logits, k_l, v_l = self._prefill(jnp.asarray(padded))
-                for i, spec in enumerate(specs):
-                    out[spec.req_id] = (logits[i, spec.prompt_len - 1],
-                                        k_l[:, i, : spec.prompt_len],
-                                        v_l[:, i, : spec.prompt_len])
-            return out
-
         def admit(req: Request, at: int, last_logits, k_rows, v_rows) -> None:
             nonlocal d_tokens, d_lengths, d_active, d_btables
             spec = req.spec
@@ -541,16 +623,16 @@ class ServingEngine:
             nonlocal tokens_total, energy_uj
             tokens_total += 1
             # charged exactly once per admission, at the prompt's TRUE row
-            # count (not the padded bucket, not the prefill group size); the
-            # first token comes off the prefill's last logits, so no decode
-            # tick is charged for it — tests/test_paged_fused.py pins
-            # energy == prefill(P) + decode-per-tick against the event
-            # stream so a double charge can never creep back in
+            # count (not the padded bucket); the first token comes off the
+            # prefill's last logits, so no decode tick is charged for it —
+            # tests/test_paged_fused.py pins energy == prefill(P) +
+            # decode-per-tick against the event stream so a double charge
+            # can never creep back in
             energy_uj += self.energy.prefill_energy_uj(spec.prompt_len)
             if req.generated >= spec.output_len:
                 finish(req, at, slot)
 
-        with self._mesh, self._scope():
+        with jax.set_mesh(self._mesh), self._scope():
             while waiting or any(active):
                 if step > max_steps:
                     raise RuntimeError("serving loop exceeded its step bound "
@@ -584,11 +666,14 @@ class ServingEngine:
                 # same-step admissions share one prefill call per bucket
                 admitted = scheduler.admissions(step, list(waiting),
                                                 int(active.sum()), cache)
-                if admitted:
-                    prefills = prefill_admissions(admitted)
-                    for req in admitted:
-                        waiting.remove(req)
-                        admit(req, step, *prefills[req.spec.req_id])
+                prompts = [self.prompt_tokens(r.spec) for r in admitted]
+                if self.batched_prefill:
+                    rows = self._prefill_rows(prompts)
+                else:
+                    rows = [self._prefill_rows([p])[0] for p in prompts]
+                for req, row in zip(admitted, rows):
+                    waiting.remove(req)
+                    admit(req, step, *row)
                 step += 1
 
         lat = np.array([r.latency for r in finished])

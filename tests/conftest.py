@@ -13,13 +13,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # tolerance- or bit-exactness-based and do not depend on XLA fusion choices.
 os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 
-# NOTE: the persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR) is
-# deliberately NOT enabled process-wide: on this jax/CPU build it corrupts
-# the CPU client once the train/serve loop is involved (aborts/segfaults in
-# later checkpoint saves even when the cache is config.update()-disabled for
-# the affected module — reproduced via test_fault_tolerance).  Only the
-# isolated subprocess tests (test_pipeline, test_multidevice) opt in.
-
 import numpy as np
 import pytest
 
@@ -27,21 +20,6 @@ import pytest
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
-
-
-def disable_compilation_cache():
-    """Module-scoped generator: cache off on entry, restored on exit.
-
-    Usage (in modules that drive the train/serve loops):
-
-        _no_xla_cache = pytest.fixture(autouse=True, scope="module")(
-            conftest.disable_compilation_cache)
-    """
-    import jax
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    yield
-    jax.config.update("jax_compilation_cache_dir", old)
 
 
 def restore_design_registry():

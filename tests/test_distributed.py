@@ -13,14 +13,6 @@ from repro.launch.mesh import single_device_mesh
 from repro.models import model as M
 from repro.optim import AdamWConfig
 
-import conftest
-
-# The persistent compilation cache segfaults on this jax/CPU build when the
-# train/serve loop reloads donated step executables (see tests/conftest.py);
-# run this module with the cache off.
-_no_xla_cache = pytest.fixture(autouse=True, scope="module")(
-    conftest.disable_compilation_cache)
-
 
 @pytest.fixture(scope="module")
 def mesh():
@@ -31,7 +23,7 @@ class TestSteps:
     def test_train_step_runs_and_descends(self, mesh, rng):
         cfg = configs.get_smoke_config("internlm2-1.8b")
         opt_cfg = AdamWConfig(lr=1e-3)
-        with mesh:
+        with jax.set_mesh(mesh):
             step = steps_lib.make_train_step(cfg, mesh, opt_cfg, donate=False)
             state = steps_lib.init_train_state(cfg, opt_cfg, jax.random.PRNGKey(0))
             batch = {
@@ -48,7 +40,7 @@ class TestSteps:
 
     def test_prefill_decode_steps(self, mesh, rng):
         cfg = configs.get_smoke_config("llama3-8b")
-        with mesh:
+        with jax.set_mesh(mesh):
             params = M.init_params(cfg, jax.random.PRNGKey(0))
             pstep = steps_lib.make_prefill_step(cfg, mesh)
             dstep = steps_lib.make_decode_step(cfg, mesh)
@@ -155,7 +147,7 @@ class TestGradCompression:
     def test_int8_psum_single_device(self, mesh, rng):
         from repro.optim.compression import int8_psum
         g = {"w": jnp.asarray(rng.normal(0, 1, (32, 32)), jnp.float32)}
-        with mesh:
+        with jax.set_mesh(mesh):
             out = int8_psum(g, mesh, axis="data")
         np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                    rtol=0.02, atol=0.02)
@@ -163,7 +155,7 @@ class TestGradCompression:
     def test_compressed_train_step(self, mesh, rng):
         cfg = configs.get_smoke_config("phi3-mini-3.8b")
         opt_cfg = AdamWConfig(lr=1e-3, compress_grads=True)
-        with mesh:
+        with jax.set_mesh(mesh):
             step = steps_lib.make_train_step(cfg, mesh, opt_cfg, donate=False)
             state = steps_lib.init_train_state(cfg, opt_cfg, jax.random.PRNGKey(0))
             batch = {

@@ -13,14 +13,6 @@ from repro.checkpoint import CheckpointManager, latest_step, restore, save
 from repro.data import DataConfig, SyntheticLM, make_pipeline
 from repro.runtime import StragglerWatchdog, plan_mesh, retry_with_backoff
 
-import conftest
-
-# The persistent compilation cache segfaults on this jax/CPU build when the
-# train/serve loop reloads donated step executables (see tests/conftest.py);
-# run this module with the cache off.
-_no_xla_cache = pytest.fixture(autouse=True, scope="module")(
-    conftest.disable_compilation_cache)
-
 
 class TestCheckpoint:
     def _tree(self, rng):

@@ -508,7 +508,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
-from repro import backends, configs, compat
+from repro import backends, configs
 from repro.backends.plan import BackendPlan, SiteAssignment
 from repro.eval import planner
 from repro.models import common, model as model_lib
@@ -573,8 +573,8 @@ with backends.use_backend("bgemm", bits=8) as execution:
     def body(xs):
         with backends.site_scope("inner"):
             return common.dense(w2, xs, name="w")
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                          check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                       check_vma=False)
     out_sharded = fn(x2)
 assert [c.site for c in execution.calls] == ["inner/w"]
 with backends.use_backend("bgemm", bits=8):

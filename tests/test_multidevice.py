@@ -30,7 +30,7 @@ kc = jnp.asarray(rng.normal(0, 1, (B, S, KVH, D)), jnp.float32)
 vc = jnp.asarray(rng.normal(0, 1, (B, S, KVH, D)), jnp.float32)
 pos = 19  # only the first pos+1 cache slots are valid
 
-with mesh:
+with jax.set_mesh(mesh):
     q_s = jax.device_put(q, NamedSharding(mesh, P("data")))
     kc_s = jax.device_put(kc, NamedSharding(mesh, P("data", "model")))
     vc_s = jax.device_put(vc, NamedSharding(mesh, P("data", "model")))
@@ -57,7 +57,7 @@ qn = jnp.asarray(rng.normal(0, 1, (B, 1, 4, m.nope_head_dim)), jnp.float32)
 qr = jnp.asarray(rng.normal(0, 1, (B, 1, 4, m.rope_head_dim)), jnp.float32)
 ckv = jnp.asarray(rng.normal(0, 1, (B, S, m.kv_lora_rank)), jnp.float32)
 kr = jnp.asarray(rng.normal(0, 1, (B, S, m.rope_head_dim)), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     ckv_s = jax.device_put(ckv, NamedSharding(mesh, P("data", "model")))
     kr_s = jax.device_put(kr, NamedSharding(mesh, P("data", "model")))
     ctx = A._mla_sharded_decode(params, qn, qr, ckv_s, kr_s, cfg,
@@ -77,7 +77,7 @@ mcfg = ModelConfig(family="moe", d_model=32, d_ff=64, vocab_size=64,
                                  capacity_factor=8.0))
 mparams = init_tree(MOE.moe_defs(mcfg), jax.random.PRNGKey(1), jnp.float32)
 x = jnp.asarray(rng.normal(0, 1, (2, 16, 32)), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     out_ep, aux = MOE.moe_fwd(mparams, x, mcfg)      # EP over model=4
 out_ref, _ = MOE.moe_fwd(mparams, x, mcfg)           # no mesh -> local path
 err = float(jnp.max(jnp.abs(out_ep - out_ref)))
@@ -88,7 +88,7 @@ print("MOE_EP_OK", err)
 import dataclasses as dc
 mcfg_a2a = mcfg.replace(moe=dc.replace(mcfg.moe, ep_impl="a2a"))
 xa = jnp.asarray(rng.normal(0, 1, (2, 16, 32)), jnp.float32)   # T=32 >= 4*4
-with mesh:
+with jax.set_mesh(mesh):
     out_a2a, _ = MOE.moe_fwd(mparams, xa, mcfg_a2a)
     out_psum, _ = MOE.moe_fwd(mparams, xa, mcfg)
 err = float(jnp.max(jnp.abs(out_a2a - out_psum)))
@@ -98,7 +98,7 @@ print("MOE_A2A_OK", err)
 # ---- 4. int8 compressed all-reduce over data axis ---------------------------
 from repro.optim.compression import int8_psum
 g = {"w": jnp.asarray(rng.normal(0, 1, (64, 64)), jnp.float32)}
-with mesh:
+with jax.set_mesh(mesh):
     out = int8_psum(g, mesh, axis="data")
 # with identical replicas the psum returns n_data * g (up to int8 rounding)
 rel = float(jnp.max(jnp.abs(out["w"] - 2 * g["w"])) / jnp.max(jnp.abs(2 * g["w"])))

@@ -37,7 +37,6 @@ try:
 except ModuleNotFoundError:  # CI image has no hypothesis; use the local shim
     from _hypothesis_fallback import given, settings, strategies as st
 
-import conftest
 from repro import backends, configs
 from repro.analysis import plan_lint, source_lint
 from repro.backends.plan import BackendPlan, SiteAssignment
@@ -49,9 +48,6 @@ from repro.launch import serve as serve_lib
 from repro.launch.mesh import single_device_mesh
 from repro.models import common, model as model_lib
 from repro.serving import ServingEngine, TrafficConfig, generate_trace
-
-_no_xla_cache = pytest.fixture(autouse=True, scope="module")(
-    conftest.disable_compilation_cache)
 
 #: every registered spec, stochastic ones pinned to a short stream
 ALL_SPECS = tuple(

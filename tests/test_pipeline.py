@@ -40,7 +40,7 @@ def stage_fn(stage_params, h):
 
 mesh = make_mesh((4,), ("pod",))
 staged = split_stages((ws, bs), 4)
-with mesh:
+with jax.set_mesh(mesh):
     out_pipe = pipeline_apply(stage_fn, staged, x, mesh)
 out_ref = sequential((ws, bs), x)
 err = float(jnp.max(jnp.abs(out_pipe - out_ref)))
@@ -48,9 +48,10 @@ assert err < 1e-5, f"forward mismatch {err}"
 
 # gradient equivalence: grad wrt weights through the pipeline
 def loss_pipe(params):
+    # pipeline_apply's shard_map names its mesh; set_mesh cannot be
+    # entered under the grad trace
     staged = split_stages(params, 4)
-    with mesh:
-        return jnp.sum(pipeline_apply(stage_fn, staged, x, mesh) ** 2)
+    return jnp.sum(pipeline_apply(stage_fn, staged, x, mesh) ** 2)
 
 def loss_ref(params):
     return jnp.sum(sequential(params, x) ** 2)

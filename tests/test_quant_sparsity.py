@@ -1,5 +1,6 @@
 """Quantization + sparsity profiling (Table V machinery)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -60,6 +61,28 @@ class TestSparsity:
         # all zeros -> full sparsity
         q = jnp.zeros((64, 64), jnp.int8)
         assert float(sparsity.bit_sparsity_blockmax(q, 8)) == pytest.approx(1.0)
+
+    @pytest.mark.parametrize("shape", [(64, 64), (70, 45), (3, 40, 96),
+                                       (100,)])
+    @pytest.mark.parametrize("bits", [2, 4, 8])
+    def test_blockmax_bands_equal_whole_tensor(self, rng, shape, bits):
+        """The band-wise block maxima (which keep a stacked full-width
+        weight within device memory) reproduce the whole-tensor statistic
+        bit for bit, ragged edges and stacked layers included."""
+        def whole_tensor(q, bits, block=32):
+            x = jnp.abs(q.astype(jnp.float32))
+            x = x[None, :] if x.ndim == 1 else x.reshape(-1, x.shape[-1])
+            r, c = x.shape
+            x = jnp.pad(x, ((0, (-r) % block), (0, (-c) % block)))
+            x = x.reshape(x.shape[0] // block, block, x.shape[1] // block,
+                          block)
+            blk = jnp.max(x, axis=(1, 3))[:-(-r // block), :-(-c // block)]
+            return 1.0 - jnp.mean(blk) / 2 ** (bits - 1)
+
+        x = jnp.asarray(rng.standard_t(3, shape), jnp.float32)
+        q = quantize(x, bits=bits, per_channel=False).values
+        assert float(sparsity.bit_sparsity_blockmax(q, bits)) == \
+            float(jax.jit(whole_tensor, static_argnums=1)(q, bits))
 
     def test_blockmax_below_elementwise(self, rng):
         """Block-max sparsity (paper's latency-relevant stat) is a lower

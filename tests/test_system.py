@@ -12,14 +12,6 @@ from repro.core import accounting, gemm_sims as gs
 from repro.launch.mesh import single_device_mesh
 from repro.models import model as M
 
-import conftest
-
-# The persistent compilation cache segfaults on this jax/CPU build when the
-# train/serve loop reloads donated step executables (see tests/conftest.py);
-# run this module with the cache off.
-_no_xla_cache = pytest.fixture(autouse=True, scope="module")(
-    conftest.disable_compilation_cache)
-
 
 class TestQuantizedExecution:
     def test_quant_kernel_inference_close_to_float(self, rng):
@@ -53,10 +45,13 @@ class TestQuantizedExecution:
 
 
 class TestUGEMMAccuracyClaim:
-    def test_model_level_accuracy_drop(self, rng):
+    def test_model_level_accuracy_drop(self):
         """Paper §V: quantized-model accuracy drops under uGEMM's stochastic
         compute (96.08 -> 94.7 on their MLP) but stays usable; measured here
         as top-1 logits agreement vs the exact INT8 path."""
+        # its own generator: the session ``rng``'s state depends on which
+        # tests ran before this one in the same worker
+        rng = np.random.default_rng(0)
         cfg = configs.get_smoke_config("internlm2-1.8b").replace(
             compute_dtype="float32")
         params = M.init_params(cfg, jax.random.PRNGKey(0))
@@ -93,7 +88,7 @@ class TestEndToEndEnergyAccounting:
         from repro.launch.serve import generate
         cfg = configs.get_smoke_config("internlm2-1.8b")
         mesh = single_device_mesh()
-        with mesh:
+        with jax.set_mesh(mesh):
             params = M.init_params(cfg, jax.random.PRNGKey(0))
         prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 8)), jnp.int32)
         toks = generate(cfg, params, mesh, prompt, max_new=6)
@@ -109,7 +104,7 @@ class TestBackendExecution:
         from repro.launch import serve
         cfg = configs.get_smoke_config("llama3-8b")
         mesh = single_device_mesh()
-        with mesh:
+        with jax.set_mesh(mesh):
             params = M.init_params(cfg, jax.random.PRNGKey(0))
         prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 8)), jnp.int32)
         backend = backends.resolve("tubgemm", bits=4)
