@@ -43,6 +43,8 @@ import dataclasses
 import math
 import threading
 
+import jax
+
 from repro.backends.base import GemmBackend
 
 # NOTE: repro.backends.registry is imported lazily inside use_backend —
@@ -200,12 +202,15 @@ def site_scope(segment: str):
 
     Model code wraps sub-module forwards so the ``dense`` calls inside
     compose the parameter-tree path (see the module-level naming contract).
-    Entered at trace time; nests and unwinds on exceptions.
+    Entered at trace time; nests and unwinds on exceptions.  The segment is
+    also a ``jax.named_scope``, so every op inside carries the path in the
+    HLO's ``op_name`` metadata (metadata only: the program is unchanged).
     """
     stack = _site_stack()
     stack.append(str(segment))
     try:
-        yield
+        with jax.named_scope(str(segment)):
+            yield
     finally:
         stack.pop()
 

@@ -350,7 +350,9 @@ def _out_proj(params, attn_out, cfg):
         h, hd, d = wo.shape
         x2 = attn_out.reshape(*attn_out.shape[:-2], h * hd)
         return dense(wo.reshape(h * hd, d), x2, cfg, name="wo")
-    return jnp.einsum("bshd,hde->bse", attn_out, wo.astype(attn_out.dtype))
+    with jax.named_scope("wo"):
+        return jnp.einsum("bshd,hde->bse", attn_out,
+                          wo.astype(attn_out.dtype))
 
 
 def _mla_fwd(params, x, cfg, *, positions, cache, cache_pos, kv_valid_len):

@@ -297,7 +297,17 @@ def dense(w: jax.Array, x: jax.Array, cfg: ModelConfig | None = None,
        (deterministic integer GEMM); uGEMM adds its stochastic multiplier
        error via the LUT path.
     3. The plain float matmul (default).
+
+    A named site also enters ``jax.named_scope(name)``, so the HLO's
+    ``op_name`` carries the whole site path and a device trace can charge
+    each GEMM to its site.
     """
+    with jax.named_scope(name) if name else contextlib.nullcontext():
+        return _dense(w, x, cfg, name)
+
+
+def _dense(w: jax.Array, x: jax.Array, cfg: ModelConfig | None,
+           name: str | None) -> jax.Array:
     from repro.backends import runtime as backend_runtime
     execution = backend_runtime.active_execution()
     if execution is not None:

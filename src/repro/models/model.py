@@ -114,7 +114,8 @@ def _logits_out(params, cfg: ModelConfig, x):
             # (in particular it never enters the cfg.quant_kernel path)
             logits = dense(params["lm_head"], x, cfg, name="lm_head")
         else:
-            logits = jnp.matmul(x, params["lm_head"].astype(x.dtype))
+            with jax.named_scope("lm_head"):
+                logits = jnp.matmul(x, params["lm_head"].astype(x.dtype))
         if cfg.logit_softcap is not None:
             logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
     return shard(logits, "batch", None, "vocab")

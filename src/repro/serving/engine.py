@@ -55,6 +55,7 @@ from repro.models import rope as rope_lib
 from repro.models.common import activation_scale_mode, dense, rmsnorm
 from repro.models.config import ModelConfig
 from repro.models.mlp import mlp_fwd
+from repro.serving import spans as spans_lib
 from repro.serving.energy import EnergyModel
 from repro.serving.paged_kv import PagedKVCache
 from repro.serving.scheduler import (Request, RequestState, _SchedulerBase,
@@ -84,6 +85,9 @@ PREFILL_LOGIT_TOL = 1e-4
 #: long-lived benchmark process.
 PREFILL_CACHE_MAXSIZE = 32
 _PREFILL_FNS: OrderedDict[tuple, object] = OrderedDict()
+
+#: the recorder's per-request events that also enter ``ServingReport.events``
+_REPORT_EVENTS = {"admitted": "admit", "evicted": "evict"}
 
 
 def _prefill_cache_get(key: tuple, make):
@@ -415,27 +419,14 @@ class ServingEngine:
                     v = dense(lp["attn"]["wv"], h, cfg, name="wv")
                     q = rope_lib.apply_rope(q, positions, cfg.rope_theta)
                     k = rope_lib.apply_rope(k, positions, cfg.rope_theta)
-                    pk = paged_lib.write_kv_token(pk, block_tables, lengths,
-                                                  k[:, 0], self.page_size)
-                    pv = paged_lib.write_kv_token(pv, block_tables, lengths,
-                                                  v[:, 0], self.page_size)
-                    if self.attention == "fused":
-                        walk = functools.partial(
-                            fused_lib.fused_paged_decode_attention,
-                            num_heads=cfg.num_heads, impl=self.attention_impl,
-                            interpret=self._fused_interpret)
-                        if self._mesh.size > 1:
-                            # XLA cannot partition a Mosaic kernel: on a
-                            # PE-grid mesh every chip walks the whole
-                            # (replicated) pools
-                            walk = jax.shard_map(
-                                walk, mesh=self._mesh, in_specs=(P(),) * 5,
-                                out_specs=P(), check_vma=False)
-                        out = walk(q, pk, pv, block_tables, lengths + 1)
-                    else:
-                        out = paged_lib.paged_decode_attention(
-                            q, pk, pv, block_tables, lengths + 1,
-                            num_heads=cfg.num_heads)
+                    with jax.named_scope("kv_write"):
+                        pk = paged_lib.write_kv_token(
+                            pk, block_tables, lengths, k[:, 0], self.page_size)
+                        pv = paged_lib.write_kv_token(
+                            pv, block_tables, lengths, v[:, 0], self.page_size)
+                    with jax.named_scope("page_walk"):
+                        out = self._page_walk(q, pk, pv, block_tables,
+                                              lengths + 1)
                     out = attn_lib._out_proj(lp["attn"], out, cfg)
                 xh = xh + out
                 h2 = rmsnorm(lp["ln2"], xh, cfg.rms_eps)
@@ -443,12 +434,33 @@ class ServingEngine:
                     xh = xh + mlp_fwd(lp["mlp"], h2, cfg)
             return xh, (pk, pv)
 
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], k_pool, v_pool))
+        # ``decode_layers``, ``kv_write`` and ``page_walk`` name the step's
+        # parts in the HLO's op_name metadata, beside the site scopes
+        with jax.named_scope("decode_layers"):
+            x, (new_k, new_v) = jax.lax.scan(
+                body, x, (params["layers"], k_pool, v_pool))
         logits = model_lib.logits_out(params, cfg, x)
         # lengths advance on-device so the host never re-uploads them
         new_lengths = jnp.where(active, lengths + 1, lengths)
         return logits, new_k, new_v, new_lengths
+
+    def _page_walk(self, q, pk, pv, block_tables, lengths):
+        """One layer's attention of each row's query over its pages."""
+        cfg = self.cfg
+        if self.attention == "fused":
+            walk = functools.partial(
+                fused_lib.fused_paged_decode_attention,
+                num_heads=cfg.num_heads, impl=self.attention_impl,
+                interpret=self._fused_interpret)
+            if self._mesh.size > 1:
+                # XLA cannot partition a Mosaic kernel: on a PE-grid mesh
+                # every chip walks the whole (replicated) pools
+                walk = jax.shard_map(walk, mesh=self._mesh,
+                                     in_specs=(P(),) * 5, out_specs=P(),
+                                     check_vma=False)
+            return walk(q, pk, pv, block_tables, lengths)
+        return paged_lib.paged_decode_attention(
+            q, pk, pv, block_tables, lengths, num_heads=cfg.num_heads)
 
     def _prefill_cache_key(self, s: int) -> tuple:
         """Everything a compiled prefill's trace depends on, besides params.
@@ -484,7 +496,8 @@ class ServingEngine:
         fn = _prefill_cache_get(self._prefill_cache_key(s), make)
         return fn(self._exec_params, tokens)
 
-    def _prefill_rows(self, prompts) -> list[tuple]:
+    def _prefill_rows(self, prompts, spans=spans_lib.NULL,
+                      req_ids=None) -> list[tuple]:
         """Per prompt, (last-logits row, K rows, V rows) of its prefill.
 
         Prompts sharing a ``_bucket(len)`` run in one call, and every call
@@ -494,6 +507,9 @@ class ServingEngine:
         nor its neighbours (causal attention, row-wise matmuls), so a
         request's KV and first token are a function of its own prompt
         whichever requests it was admitted with.
+
+        ``spans`` records each call's padding, call and slicing, under the
+        ids ``req_ids`` gives the prompts.
         """
         groups: dict[int, list[int]] = {}
         for i, p in enumerate(prompts):
@@ -502,13 +518,20 @@ class ServingEngine:
         for width, idx in groups.items():
             for lo in range(0, len(idx), self.max_batch):
                 chunk = idx[lo: lo + self.max_batch]
-                padded = np.zeros((self.max_batch, width), np.int32)
-                for r, i in enumerate(chunk):
-                    padded[r, : len(prompts[i])] = prompts[i]
-                logits, k_l, v_l = self._prefill(jnp.asarray(padded))
-                for r, i in enumerate(chunk):
-                    p = len(prompts[i])
-                    out[i] = (logits[r, p - 1], k_l[:, r, :p], v_l[:, r, :p])
+                reqs = () if req_ids is None else tuple(req_ids[i]
+                                                        for i in chunk)
+                with spans.span("prefill.pad", req=reqs, rows=len(chunk)):
+                    padded = np.zeros((self.max_batch, width), np.int32)
+                    for r, i in enumerate(chunk):
+                        padded[r, : len(prompts[i])] = prompts[i]
+                    toks = jnp.asarray(padded)
+                with spans.span("prefill.call", req=reqs):
+                    logits, k_l, v_l = self._prefill(toks)
+                with spans.span("prefill.slice", req=reqs):
+                    for r, i in enumerate(chunk):
+                        p = len(prompts[i])
+                        out[i] = (logits[r, p - 1], k_l[:, r, :p],
+                                  v_l[:, r, :p])
         return out
 
     # -- host-side serving loop -----------------------------------------------
@@ -528,7 +551,8 @@ class ServingEngine:
         return contextlib.nullcontext()
 
     def run(self, trace: tuple[TrafficRequest, ...],
-            scheduler: str | _SchedulerBase = "continuous") -> ServingReport:
+            scheduler: str | _SchedulerBase = "continuous",
+            spans: spans_lib.SpanRecorder | None = None) -> ServingReport:
         """Serve ``trace`` to completion; returns the metrics report.
 
         Per step: (1) one jitted decode step advances every running request
@@ -536,6 +560,12 @@ class ServingEngine:
         slot zeroed); (2) the scheduler admits arrivals into freed slots —
         admitted requests prefill now (their first token counts this step)
         and join decode from the next step.
+
+        ``spans`` records the loop's spans, counts and per-request events
+        (``serving/spans.py``; the names are in ``docs/SERVING.md``).
+        Without one the loop records nothing, except in the steps during
+        which a profiler records this process: their phases are then
+        mirrored into its trace.
         """
         if not trace:
             raise ValueError("empty traffic trace")
@@ -583,6 +613,15 @@ class ServingEngine:
         step = 0
         max_steps = (max(r.arrival_step for r in trace)
                      + 2 * sum(r.output_len + 1 for r in trace) + 16)
+        follow = spans is None      # record only while a profiler records
+        rec = spans_lib.NULL if follow else spans
+
+        def mark(name: str, req_id: int, at: int) -> None:
+            """A per-request event, stamped now; admissions and evictions
+            also enter the report's event stream."""
+            rec.event(name, req_id)
+            if name in _REPORT_EVENTS:
+                events.append((at, _REPORT_EVENTS[name], req_id))
 
         def finish(req: Request, at: int, slot: int) -> None:
             nonlocal d_tokens, d_lengths, d_active, d_btables
@@ -597,29 +636,35 @@ class ServingEngine:
             d_active = d_active.at[slot].set(False)
             d_btables = d_btables.at[slot].set(0)   # back to the trash page
             finished.append(req)
-            events.append((at, "evict", req.req_id))
+            mark("evicted", req.req_id, at)
 
         def admit(req: Request, at: int, last_logits, k_rows, v_rows) -> None:
             nonlocal d_tokens, d_lengths, d_active, d_btables
             spec = req.spec
-            cache.allocate(spec.req_id, spec.total_len)
-            cache.write_prefill(spec.req_id, k_rows, v_rows)
-            first = int(jnp.argmax(last_logits))
-            slot = next(i for i in range(b) if slot_req[i] is None)
-            slot_req[slot] = req
-            lengths[slot] = spec.prompt_len
-            active[slot] = True
-            d_tokens = d_tokens.at[slot, 0].set(first)
-            d_lengths = d_lengths.at[slot].set(spec.prompt_len)
-            d_active = d_active.at[slot].set(True)
-            d_btables = d_btables.at[slot].set(
-                jnp.asarray(cache.block_table_row(spec.req_id), jnp.int32))
+            rid = spec.req_id
+            cache.allocate(rid, spec.total_len)
+            with rec.span("kv.write_prefill", req=rid,
+                          pages=cache.pages_needed(spec.prompt_len)):
+                cache.write_prefill(rid, k_rows, v_rows)
+            with rec.span("admit.first_token", req=rid):
+                first = int(jnp.argmax(last_logits))
+            mark("first_token", rid, at)
+            with rec.span("admit.tables", req=rid):
+                row = jnp.asarray(cache.block_table_row(rid), jnp.int32)
+                slot = next(i for i in range(b) if slot_req[i] is None)
+                slot_req[slot] = req
+                lengths[slot] = spec.prompt_len
+                active[slot] = True
+                d_tokens = d_tokens.at[slot, 0].set(first)
+                d_lengths = d_lengths.at[slot].set(spec.prompt_len)
+                d_active = d_active.at[slot].set(True)
+                d_btables = d_btables.at[slot].set(row)
             req.state = RequestState.RUNNING
             req.admitted_step = at
             req.slot = slot
             req.generated = 1
-            req_tokens[spec.req_id].append(first)
-            events.append((at, "admit", spec.req_id))
+            req_tokens[rid].append(first)
+            mark("admitted", rid, at)
             nonlocal tokens_total, energy_uj
             tokens_total += 1
             # charged exactly once per admission, at the prompt's TRUE row
@@ -632,48 +677,65 @@ class ServingEngine:
             if req.generated >= spec.output_len:
                 finish(req, at, slot)
 
-        with jax.set_mesh(self._mesh), self._scope():
+        with jax.set_mesh(self._mesh), self._scope(), \
+                rec.span("serve.run", leaf=False):
             while waiting or any(active):
                 if step > max_steps:
                     raise RuntimeError("serving loop exceeded its step bound "
                                        "— scheduler stuck?")
-                # 1) decode the running set (admitted before this step)
-                n_active = int(active.sum())
-                if n_active:
-                    logits, k_pool, v_pool, d_lengths = self._decode(
-                        self._exec_params, d_tokens, cache.k_pool,
-                        cache.v_pool, d_btables, d_lengths, d_active)
-                    cache.sync_pools(k_pool, v_pool)
-                    nxt_dev = jnp.argmax(logits[:, 0],
-                                         axis=-1).astype(jnp.int32)
-                    d_tokens = nxt_dev[:, None]
-                    nxt = np.asarray(nxt_dev)
-                    decode_ticks += 1
-                    decoded_slots += n_active
-                    energy_uj += self.energy.decode_energy_uj(n_active)
-                    for slot in range(b):
-                        req = slot_req[slot]
-                        if req is None:
-                            continue
-                        lengths[slot] += 1          # KV written for the input
-                        cache.lengths[req.req_id] = int(lengths[slot])
-                        req.generated += 1
-                        req_tokens[req.req_id].append(int(nxt[slot]))
-                        tokens_total += 1
-                        if req.generated >= req.spec.output_len:
-                            finish(req, step, slot)
-                # 2) step boundary: admit arrivals (join decode next step);
-                # same-step admissions share one prefill call per bucket
-                admitted = scheduler.admissions(step, list(waiting),
-                                                int(active.sum()), cache)
-                prompts = [self.prompt_tokens(r.spec) for r in admitted]
-                if self.batched_prefill:
-                    rows = self._prefill_rows(prompts)
-                else:
-                    rows = [self._prefill_rows([p])[0] for p in prompts]
-                for req, row in zip(admitted, rows):
-                    waiting.remove(req)
-                    admit(req, step, *row)
+                if follow:
+                    rec = spans_lib.follow_profiler(rec)
+                with rec.step_span(step) as step_span:
+                    # 1) decode the running set (admitted before this step)
+                    n_active = int(active.sum())
+                    if n_active:
+                        with rec.span("decode.dispatch", rows=n_active):
+                            logits, k_pool, v_pool, d_lengths = self._decode(
+                                self._exec_params, d_tokens, cache.k_pool,
+                                cache.v_pool, d_btables, d_lengths, d_active)
+                            cache.sync_pools(k_pool, v_pool)
+                            nxt_dev = jnp.argmax(logits[:, 0],
+                                                 axis=-1).astype(jnp.int32)
+                            d_tokens = nxt_dev[:, None]
+                        with rec.span("decode.read_tokens"):
+                            nxt = np.asarray(nxt_dev)
+                        with rec.span("decode.bookkeep") as bookkeep:
+                            decode_ticks += 1
+                            decoded_slots += n_active
+                            energy_uj += self.energy.decode_energy_uj(n_active)
+                            for slot in range(b):
+                                req = slot_req[slot]
+                                if req is None:
+                                    continue
+                                lengths[slot] += 1  # KV written for the input
+                                cache.lengths[req.req_id] = int(lengths[slot])
+                                req.generated += 1
+                                req_tokens[req.req_id].append(int(nxt[slot]))
+                                tokens_total += 1
+                                if req.generated >= req.spec.output_len:
+                                    finish(req, step, slot)
+                                    bookkeep.count(evictions=1)
+                    # 2) step boundary: admit arrivals (join decode next
+                    # step); same-step admissions share one prefill call per
+                    # bucket
+                    with rec.span("schedule"):
+                        admitted = scheduler.admissions(
+                            step, list(waiting), int(active.sum()), cache)
+                    if admitted:
+                        ids = tuple(r.req_id for r in admitted)
+                        with rec.span("admit.prompts", req=ids):
+                            prompts = [self.prompt_tokens(r.spec)
+                                       for r in admitted]
+                        if self.batched_prefill:
+                            rows = self._prefill_rows(prompts, rec, ids)
+                        else:
+                            rows = [self._prefill_rows([p], rec, (i,))[0]
+                                    for p, i in zip(prompts, ids)]
+                        for req, row in zip(admitted, rows):
+                            waiting.remove(req)
+                            admit(req, step, *row)
+                    step_span.count(rows=n_active,
+                                    tokens=n_active + len(admitted))
                 step += 1
 
         lat = np.array([r.latency for r in finished])
