@@ -7,6 +7,8 @@ page ids).  This module is the device-side read/write path over that layout:
 
 * :func:`write_kv_token` scatters one new K (or V) vector per request into
   the page/slot its current length maps to;
+* :func:`write_prompt_kv` scatters one admitted prompt's K and V, a row of
+  the padded prefill call's outputs, into that request's pages;
 * :func:`gather_kv` materializes the per-request view ``(B, max_blocks *
   page_size, KVH, head_dim)`` by gathering pool pages through the block
   table;
@@ -28,7 +30,8 @@ import jax.numpy as jnp
 
 from repro.models.attention import _repeat_kv, naive_attention
 
-__all__ = ["write_kv_token", "gather_kv", "paged_decode_attention"]
+__all__ = ["write_kv_token", "write_prompt_kv", "gather_kv",
+           "paged_decode_attention"]
 
 
 def write_kv_token(pool: jax.Array, block_table: jax.Array,
@@ -47,6 +50,40 @@ def write_kv_token(pool: jax.Array, block_table: jax.Array,
         block_table, (lengths // page_size)[:, None], axis=1)[:, 0]
     slots = lengths % page_size
     return pool.at[pages, slots].set(new.astype(pool.dtype))
+
+
+def write_prompt_kv(k_pool: jax.Array, v_pool: jax.Array, k_call: jax.Array,
+                    v_call: jax.Array, row: jax.Array, length: jax.Array,
+                    page_ids: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Scatter one prompt's K and V, for every layer, into its pages.
+
+    Pools: (L, num_pages, page_size, KVH, hd); ``k_call``/``v_call``: (L,
+    B, W, KVH, hd), the padded outputs of the prefill call that ran the
+    prompt; ``row``: its row in that call; ``length``: its true length;
+    ``page_ids``: (ceil(W / page_size),) int32, the pages holding its
+    positions in order, padded with the trash page 0.  Positions
+    ``< length`` of the row land in its pages; every other position of the
+    pools keeps its value (the trash page's too), so the result equals
+    ``PagedKVCache.write_prefill`` of the row's first ``length`` positions.
+    ``row``, ``length`` and ``page_ids`` may be traced: a jitted writer
+    compiles once per pool and call shape, and a caller that donates the
+    pools gets them updated in place.
+    """
+    num_layers, _, page_size = k_pool.shape[:3]
+    blocks = page_ids.shape[0]
+    pos = jnp.arange(blocks * page_size).reshape(blocks, page_size)
+    keep = (pos < length)[None, :, :, None, None]
+
+    def write(pool, call):
+        new = jax.lax.dynamic_index_in_dim(call, row, axis=1, keepdims=False)
+        pad = blocks * page_size - new.shape[1]
+        new = jnp.pad(new, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        new = new.reshape(num_layers, blocks, page_size, *new.shape[2:])
+        old = pool[:, page_ids]
+        return pool.at[:, page_ids].set(
+            jnp.where(keep, new.astype(pool.dtype), old))
+
+    return write(k_pool, k_call), write(v_pool, v_call)
 
 
 def gather_kv(pool: jax.Array, block_table: jax.Array) -> jax.Array:

@@ -6,8 +6,10 @@ step that advances *every* slot one token per scheduler step:
 
 * **prefill** (admission): the prompt runs through ``model_lib.prefill``
   (padded to a power-of-two bucket — causal attention makes the valid
-  prefix independent of tail padding), its KV is copied into freshly
-  allocated pages, and its first token comes off the prompt's last logits;
+  prefix independent of tail padding), its KV is scattered into freshly
+  allocated pages by one jitted program that updates the pools in place
+  (``kernels.paged_attention.write_prompt_kv``), and its first token comes
+  off the prompt's last logits;
 * **decode** (every step): the jitted step embeds each slot's pending
   token at its own position, scatters the new K/V into its pages
   (``kernels.paged_attention.write_kv_token``), attends over the gathered
@@ -389,6 +391,10 @@ class ServingEngine:
                 self._exec_params, _grid_shardings(self._exec_params,
                                                    self._mesh))
         self._decode = jax.jit(self._decode_fn)
+        # an admitted prompt's K and V into its pages, the pools updated in
+        # place: one program per (pool, prefill call) shape
+        self._write_prompt = jax.jit(paged_lib.write_prompt_kv,
+                                     donate_argnums=(0, 1))
 
     # -- jitted model steps ---------------------------------------------------
 
@@ -496,20 +502,22 @@ class ServingEngine:
         fn = _prefill_cache_get(self._prefill_cache_key(s), make)
         return fn(self._exec_params, tokens)
 
-    def _prefill_rows(self, prompts, spans=spans_lib.NULL,
-                      req_ids=None) -> list[tuple]:
-        """Per prompt, (last-logits row, K rows, V rows) of its prefill.
+    def _prefill_padded(self, prompts, spans=spans_lib.NULL,
+                        req_ids=None) -> list[tuple]:
+        """Per prompt, (last-logits row, its call's K, its call's V, its row
+        in the call).
 
-        Prompts sharing a ``_bucket(len)`` run in one call, and every call
-        is padded with dummy rows to ``(max_batch, bucket)``.  XLA compiles
-        each batch size differently and on a TPU the rounding follows, but
-        at one fixed shape a row's result depends on neither its position
-        nor its neighbours (causal attention, row-wise matmuls), so a
-        request's KV and first token are a function of its own prompt
-        whichever requests it was admitted with.
+        The K and V are the padded call's whole outputs, (L, max_batch,
+        bucket, KVH, hd).  Prompts sharing a ``_bucket(len)`` run in one
+        call, and every call is padded with dummy rows to ``(max_batch,
+        bucket)``.  XLA compiles each batch size differently and on a TPU
+        the rounding follows, but at one fixed shape a row's result depends
+        on neither its position nor its neighbours (causal attention,
+        row-wise matmuls), so a request's KV and first token are a function
+        of its own prompt whichever requests it was admitted with.
 
-        ``spans`` records each call's padding, call and slicing, under the
-        ids ``req_ids`` gives the prompts.
+        ``spans`` records each call's padding, call and last-logits slices,
+        under the ids ``req_ids`` gives the prompts.
         """
         groups: dict[int, list[int]] = {}
         for i, p in enumerate(prompts):
@@ -529,10 +537,16 @@ class ServingEngine:
                     logits, k_l, v_l = self._prefill(toks)
                 with spans.span("prefill.slice", req=reqs):
                     for r, i in enumerate(chunk):
-                        p = len(prompts[i])
-                        out[i] = (logits[r, p - 1], k_l[:, r, :p],
-                                  v_l[:, r, :p])
+                        out[i] = (logits[r, len(prompts[i]) - 1], k_l, v_l,
+                                  r)
         return out
+
+    def _prefill_rows(self, prompts) -> list[tuple]:
+        """Per prompt, (last-logits row, K rows, V rows) of its prefill:
+        K and V rows (L, len, KVH, hd) sliced out of its padded call."""
+        return [(last, k[:, r, :len(p)], v[:, r, :len(p)])
+                for p, (last, k, v, r) in zip(prompts,
+                                              self._prefill_padded(prompts))]
 
     # -- host-side serving loop -----------------------------------------------
 
@@ -578,6 +592,10 @@ class ServingEngine:
             num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, num_pages=self.num_pages,
             page_size=self.page_size, max_seq_len=self.max_seq_len)
+        # placed as the jitted writer and decode step return them, so a
+        # run's first admission reuses the programs its later ones compile
+        cache.sync_pools(*jax.device_put((cache.k_pool, cache.v_pool),
+                                         NamedSharding(self._mesh, P())))
         for req in trace:
             if req.total_len > cache.max_seq_len:
                 raise ValueError(f"request {req.req_id} needs {req.total_len} "
@@ -638,14 +656,23 @@ class ServingEngine:
             finished.append(req)
             mark("evicted", req.req_id, at)
 
-        def admit(req: Request, at: int, last_logits, k_rows, v_rows) -> None:
+        def admit(req: Request, at: int, last_logits, k_call, v_call,
+                  row: int) -> None:
             nonlocal d_tokens, d_lengths, d_active, d_btables
             spec = req.spec
             rid = spec.req_id
             cache.allocate(rid, spec.total_len)
-            with rec.span("kv.write_prefill", req=rid,
-                          pages=cache.pages_needed(spec.prompt_len)):
-                cache.write_prefill(rid, k_rows, v_rows)
+            pages = cache.pages_needed(spec.prompt_len)
+            with rec.span("kv.write_prefill", req=rid, pages=pages) as write:
+                # the pages from the cache's own table: ``block_table_row``
+                # is read once the first token is on the host
+                ids = np.zeros(-(-k_call.shape[2] // self.page_size), np.int32)
+                ids[:pages] = cache.block_tables[rid][:pages]
+                cache.sync_pools(*self._write_prompt(
+                    cache.k_pool, cache.v_pool, k_call, v_call, row,
+                    spec.prompt_len, ids))
+                cache.lengths[rid] = spec.prompt_len
+                write.count(dispatches=1)
             with rec.span("admit.first_token", req=rid):
                 first = int(jnp.argmax(last_logits))
             mark("first_token", rid, at)
@@ -727,9 +754,9 @@ class ServingEngine:
                             prompts = [self.prompt_tokens(r.spec)
                                        for r in admitted]
                         if self.batched_prefill:
-                            rows = self._prefill_rows(prompts, rec, ids)
+                            rows = self._prefill_padded(prompts, rec, ids)
                         else:
-                            rows = [self._prefill_rows([p], rec, (i,))[0]
+                            rows = [self._prefill_padded([p], rec, (i,))[0]
                                     for p, i in zip(prompts, ids)]
                         for req, row in zip(admitted, rows):
                             waiting.remove(req)

@@ -250,6 +250,84 @@ class TestPagedBitExact:
 
 
 # ---------------------------------------------------------------------------
+# Admission KV write: the donated device writer against write_prefill
+# ---------------------------------------------------------------------------
+
+class TestPromptWriter:
+    @pytest.mark.parametrize("page_size", [4, 3])
+    @pytest.mark.parametrize("row", [0, 2])
+    @pytest.mark.parametrize("width,length", [(8, 7), (16, 13)])
+    def test_matches_write_prefill_in_place(self, width, length, row,
+                                            page_size):
+        """One jitted call puts positions < length of the call's row into
+        the request's pages bit for bit as ``write_prefill`` does, leaves
+        every other request's pages (and the trash page) as they were, and
+        consumes the donated pools."""
+        rng = np.random.default_rng(width * 100 + row * 10 + page_size)
+        shape = dict(num_layers=2, num_kv_heads=2, head_dim=3)
+        caches = [PagedKVCache(num_pages=24, page_size=page_size,
+                               max_seq_len=32, **shape) for _ in range(2)]
+        pool = rng.normal(size=caches[0].k_pool.shape).astype(np.float32)
+        for c in caches:     # stale values everywhere, as in a served pool
+            c.sync_pools(jnp.asarray(pool), jnp.asarray(pool + 1.0))
+            c.allocate("other", 9)
+            c.allocate("new", length + 4)    # pages past the prompt too
+        k_call = rng.normal(size=(2, 3, width, 2, 3)).astype(np.float32)
+        v_call = rng.normal(size=(2, 3, width, 2, 3)).astype(np.float32)
+        eager, device = caches
+        eager.write_prefill("new", jnp.asarray(k_call[:, row, :length]),
+                            jnp.asarray(v_call[:, row, :length]))
+
+        pages = device.pages_needed(length)
+        ids = np.zeros(-(-width // page_size), np.int32)
+        ids[:pages] = device.block_tables["new"][:pages]
+        k_in, v_in = device.k_pool, device.v_pool
+        write = jax.jit(paged_lib.write_prompt_kv, donate_argnums=(0, 1))
+        device.sync_pools(*write(k_in, v_in, jnp.asarray(k_call),
+                                 jnp.asarray(v_call), row, length, ids))
+        device.lengths["new"] = length
+
+        assert k_in.is_deleted() and v_in.is_deleted()
+        for got, want in zip(device.gather_request("new"),
+                             eager.gather_request("new")):
+            np.testing.assert_array_equal(got, want)
+        mine = set(device.block_tables["new"][:pages])
+        for p in range(device.num_pages):
+            if p not in mine:
+                np.testing.assert_array_equal(
+                    np.asarray(device.k_pool[:, p]), pool[:, p])
+                np.testing.assert_array_equal(
+                    np.asarray(device.v_pool[:, p]), pool[:, p] + 1.0)
+        np.testing.assert_array_equal(np.asarray(device.k_pool),
+                                      np.asarray(eager.k_pool))
+        np.testing.assert_array_equal(np.asarray(device.v_pool),
+                                      np.asarray(eager.v_pool))
+
+    def test_one_compile_per_bucket(self, cfg, params):
+        """Prompts of many lengths in two prefill buckets compile the
+        admission writer once per bucket, the run's first admission
+        included, and a second run compiles none."""
+        from repro.serving import spans as spans_lib
+        from repro.serving.traffic import TrafficRequest
+        lens = [5, 6, 7, 8, 9, 11, 13, 16]      # buckets 8 and 16
+        trace = tuple(TrafficRequest(req_id=i, arrival_step=i,
+                                     prompt_len=n, output_len=2)
+                      for i, n in enumerate(lens))
+        # a pool size no other engine of the process has compiled for
+        eng = _engine(cfg, params, num_pages=31)
+        compiles = []
+        for _ in range(2):
+            rec = spans_lib.SpanRecorder()
+            eng.run(trace, spans=rec)
+            writes = [s for s in rec.dump()["spans"]
+                      if s["name"] == "kv.write_prefill"]
+            assert len(writes) == len(lens)
+            compiles.append([w["counts"].get("compiles", 0) for w in writes])
+        assert compiles[0] == [1, 0, 0, 0, 1, 0, 0, 0]
+        assert compiles[1] == [0] * len(lens)
+
+
+# ---------------------------------------------------------------------------
 # Schedulers: admission rules + the continuous-beats-static gate
 # ---------------------------------------------------------------------------
 

@@ -122,6 +122,8 @@ def test_events_tokens_and_pages(served):
         r.req_id for r in trace)
     assert sum(w["counts"]["pages"] for w in writes) == sum(
         math.ceil(r.prompt_len / eng.page_size) for r in trace)
+    # one device program writes a request's pages, however many they are
+    assert all(w["counts"]["dispatches"] == 1 for w in writes)
     first = {e["req"]: e["t_ns"] for e in events if e["name"] == "first_token"}
     for s in dump["spans"]:
         if s["name"] == "admit.first_token":

@@ -7,6 +7,8 @@ hand each kernel ``ShapeDtypeStruct``s placed on a *described* v5e chip
 ships in libtpu — no chip needed.  Each asserts that the Mosaic kernel
 (``tpu_custom_call``) is in the compiled program, i.e. that nothing fell
 back to an XLA lowering.
+The serving engine's admission writer, an XLA program, is held instead to
+updating the KV pools in place (``memory_analysis``).
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load libtpu, and every xdist worker imports this
@@ -26,6 +28,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.kernels import packed_gemm, unary_gemm
+from repro.kernels import paged_attention as paged_lib
 from repro.kernels import paged_attention_fused as paf
 
 # internlm2-1.8b decode widths: 4 slots, 16 query / 8 KV heads of 128,
@@ -117,3 +120,21 @@ def test_packed_gemm_compiles(one_chip, fuse_dequant):
         _sds((words, N), jnp.int32, one_chip),
         _sds((1, N), jnp.float32, one_chip))
     assert "tpu_custom_call" in text
+
+
+def test_prompt_writer_updates_the_pools_in_place(one_chip):
+    """The admission writer at internlm2-1.8b's code-cell pool (24 layers,
+    577 pages of 16, 8 KV heads of 128) and a 512-wide prefill call of 8
+    rows: both donated pools alias its outputs, and its temporaries hold
+    less than one pool, so no whole-pool copy is made."""
+    layers, pages, page, call_rows, width = 24, 577, 16, 8, 512
+    pool = _sds((layers, pages, page, KVH, HD), jnp.float32, one_chip)
+    call = _sds((layers, call_rows, width, KVH, HD), jnp.float32, one_chip)
+    scalar = _sds((), jnp.int32, one_chip)
+    mem = jax.jit(paged_lib.write_prompt_kv, donate_argnums=(0, 1)).lower(
+        pool, pool, call, call, scalar, scalar,
+        _sds((width // page,), jnp.int32, one_chip)).compile() \
+        .memory_analysis()
+    pool_bytes = layers * pages * page * KVH * HD * 4
+    assert mem.alias_size_in_bytes == 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
