@@ -2,10 +2,11 @@
 
 Once the window has closed and the engine is gone, a sample of the
 finished requests, drawn from the seed and always holding the one with the
-most served tokens, is run through the plain float32 reference
-(``reference.py``) once per request: its prompt followed by the tokens the
-engine served.  At each served position the reference's logits say how far
-the served token lies below the reference's best token.  The widest such
+most served tokens, is run through the plain float32 reference (the
+forward pass of the cell's architecture, ``archs/<arch>.py``) once per
+request: its prompt followed by the tokens the engine served.  At each
+served position the reference's logits say how far the served token lies
+below the reference's best token.  The widest such
 gap is compared with the cell's limit; greedy decoding that computes the
 model at its stated precision picks a token within rounding of the best.
 
@@ -58,12 +59,12 @@ def readings(cell, params, seed: int, served, *, control: bool = False,
         rows = np.arange(len(prompt) - 1, len(prompt) - 1 + len(out))
         chosen = np.zeros(cell.max_seq_len, np.int32)
         chosen[rows] = out
-        ref = reference.logits(params, seq, d=d)
+        ref = cell.arch.logits(params, seq, d=d)
         gaps = np.asarray(reference.gaps(ref, chosen))[rows]
         worst = max(worst, float(gaps.max()))
         rows_total += len(rows)
         if control:
-            pick = reference.first_choice(reference.logits(params, seq, d=d,
+            pick = reference.first_choice(cell.arch.logits(params, seq, d=d,
                                                            fp8=True))
             cgaps = np.asarray(reference.gaps(ref, pick))[rows]
             worst_control = max(worst_control, float(cgaps.max()))

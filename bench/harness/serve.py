@@ -2,9 +2,9 @@
 
 The timed path is the program's own ``ServingEngine.run`` with continuous
 batching, driven by the benchmark's open-loop scheduler.  The benchmark
-brings the weights (``weights.make``) and the prompts
-(``traffic.prompt_tokens``, put in place of the engine's own prompt
-generator), so the engine receives only generated inputs.
+brings the weights (``weights.make`` of the architecture's ``shapes``) and
+the prompts (``traffic.prompt_tokens``, put in place of the engine's own
+prompt generator), so the engine receives only generated inputs.
 """
 
 from __future__ import annotations
@@ -141,7 +141,7 @@ def setup(cell, seed: int, arrivals, setup_times: dict):
     """Weights, engine and warm-up for a window offering ``arrivals``."""
     from repro.serving.engine import _bucket
     t = time.perf_counter()
-    params = weights.make(cell.dims, seed)
+    params = weights.make(cell.arch.shapes(cell.dims), seed)
     jax.block_until_ready(params)
     setup_times["init_s"] = time.perf_counter() - t
     t = time.perf_counter()

@@ -78,7 +78,8 @@ def rehearse(name: str) -> None:
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
-    params = _abstract(jax.eval_shape(lambda: weights.make(cell.dims, 0)), one)
+    params = _abstract(jax.eval_shape(
+        lambda: weights.make(cell.arch.shapes(cell.dims), 0)), one)
     e = _engine(cell, params, mesh)
     e._exec_params = _abstract(e._exec_params, one)
     b, cfg = cell.max_batch, cell.cfg

@@ -13,21 +13,16 @@ for p in (str(BENCH), str(ROOT / "src")):
         sys.path.insert(0, p)
 
 
-def smoke_cell(name: str, **over):
-    """A cell of the benchmark with its model cut to the registry's smoke
-    sizes, for driving the harness on the CPU."""
+def smoke_cell(name: str, bench_root=ROOT, **over):
+    """A cell of the benchmark under ``bench_root`` with its model cut to
+    the registry's smoke sizes, for driving the harness on the CPU."""
     import dataclasses
 
     from harness import spec
     from repro.configs import get_smoke_config
 
-    cell = spec.load_cell(name)
-    reg = spec.read_json(BENCH / "configs" / f"{cell.config_name}.json")
-    cfg = get_smoke_config(reg["registry_id"]).replace(
+    cell = spec.load_cell(name, bench_root)
+    cfg = get_smoke_config(cell.cfg.arch_id).replace(
         param_dtype="bfloat16", compute_dtype="bfloat16")
-    dims = spec.Dims(layers=cfg.num_layers, d_model=cfg.d_model,
-                     heads=cfg.num_heads, kv_heads=cfg.num_kv_heads,
-                     head_dim=cfg.resolved_head_dim, d_ff=cfg.d_ff,
-                     vocab=cfg.vocab_size, rope_theta=cfg.rope_theta,
-                     rms_eps=cfg.rms_eps)
-    return dataclasses.replace(cell, cfg=cfg, dims=dims, **over)
+    return dataclasses.replace(cell, cfg=cfg,
+                               dims=cell.arch.Dims.from_model(cfg), **over)

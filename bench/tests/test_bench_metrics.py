@@ -67,7 +67,7 @@ def test_occupancy_is_rows_over_slots_of_decode_steps():
 
 def _dims(name):
     conf = spec.read_json(_paths.BENCH / "configs" / f"{name}.json")
-    return spec.Dims.from_config(conf["config"])
+    return spec.load_arch(conf["arch"]).Dims.from_config(conf["config"])
 
 
 def test_internlm2_decode_step_by_hand():

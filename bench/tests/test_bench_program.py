@@ -165,17 +165,20 @@ def test_overlap_of_unions_and_steps_without_a_successor():
 
 
 def test_hlo_reader_names_the_ops_of_a_cpu_trace(tmp_path):
+    # the trace holds every program of the process: a name no engine uses,
+    # so an engine built by an earlier test in this process is not found
     @jax.jit
-    def _decode_fn(x):
+    def _decode_fn_of_this_test(x):
         with jax.named_scope("decode_layers"), jax.named_scope("wq"):
             return jnp.tanh(x @ x)
 
     x = jnp.ones((64, 64))
-    _decode_fn(x).block_until_ready()
+    _decode_fn_of_this_test(x).block_until_ready()
     jax.profiler.start_trace(str(tmp_path))
-    _decode_fn(x).block_until_ready()
+    _decode_fn_of_this_test(x).block_until_ready()
     jax.profiler.stop_trace()
-    found = hlo.op_names(program.newest(str(tmp_path)), "_decode_fn")
+    found = hlo.op_names(program.newest(str(tmp_path)),
+                         "_decode_fn_of_this_test")
     assert len(found) == 1
     names = next(iter(found.values()))
     assert any(op_name.endswith("decode_layers/wq/dot_general")
@@ -212,7 +215,7 @@ def smoke():
            "output": {"dist": "uniform", "min": 2, "max": 9}}
     cell = _paths.smoke_cell("phi3-mini-3.8b.float.longgen", traffic=mix,
                              max_batch=3, page_size=4, max_seq_len=64)
-    params = serve.weights.make(cell.dims, seed=5)
+    params = serve.weights.make(cell.arch.shapes(cell.dims), seed=5)
     engine = serve.build_engine(cell, params, seed=5)
     arrivals = traffic.arrivals(mix, 20.0, 0.6, seed=5)
     serve.warm_up(cell, engine, arrivals, _bucket)

@@ -118,7 +118,7 @@ def test_drives_run_to_completion_on_smoke_configs(name, mode):
     cell = _paths.smoke_cell(
         name, traffic=mix, max_batch=3, page_size=4, max_seq_len=64,
         mode=spec.read_json(_paths.BENCH / "modes" / f"{mode}.json"))
-    params = serve.weights.make(cell.dims, seed=3)
+    params = serve.weights.make(cell.arch.shapes(cell.dims), seed=3)
     engine = serve.build_engine(cell, params, seed=3)
     arrivals = traffic.arrivals(mix, 20.0, 0.6, seed=3)
     serve.warm_up(cell, engine, arrivals, _bucket)

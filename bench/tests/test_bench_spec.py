@@ -86,13 +86,37 @@ def test_the_check_fits_with_a_full_benchmark():
     assert total <= 43200
 
 
+# a width: hidden, intermediate, latent, state or projection sizes, keys
+# ending in _dim or _rank, head sizes and counts, expansion factors, experts
+# per token.  Depth (num_hidden_layers) and the experts held may be cut.
+WIDTHS = re.compile(r"(hidden_size|hidden_dim|intermediate|latent|state|"
+                    r"projection|head|expan|_dim$|_rank$|experts_per_tok)")
+
+
+@pytest.mark.parametrize("key", [
+    "hidden_size", "intermediate_size", "moe_intermediate_size", "head_dim",
+    "v_head_dim", "num_attention_heads", "num_key_value_heads",
+    "kv_lora_rank", "q_lora_rank", "ssm_state_size", "mamba_d_state",
+    "mamba_expand", "expansion_factor", "num_experts_per_tok",
+    "projection_size", "kv_latent_dim"])
+def test_a_width_may_not_be_cut(key):
+    assert WIDTHS.search(key)
+
+
+@pytest.mark.parametrize("key", [
+    "num_hidden_layers", "num_local_experts", "num_experts",
+    "n_routed_experts", "max_position_embeddings"])
+def test_depth_experts_held_and_context_may_be_cut(key):
+    assert not WIDTHS.search(key)
+
+
 def test_configs_are_used_and_reduce_no_width():
     used = {w["config"] for w in BENCH["workloads"]}
     assert used == {c["name"] for c in BENCH["configs"]}
-    widths = re.compile(r"(hidden|intermediate|latent|state|projection|"
-                        r"head|expansion|_dim$|_rank$|experts_per_tok)")
     for c in BENCH["configs"]:
         assert len(c["reduced"]) <= 16
-        assert not any(widths.search(k) for k in c["reduced"])
+        assert not any(WIDTHS.search(k) for k in c["reduced"])
         conf = spec.read_json(_paths.ROOT / c["file"])
         assert set(c["reduced"]) == set(conf["reduced"])
+        assert set(conf["reduced"]) <= set(conf["published"])
+        assert spec.load_arch(conf["arch"]).__name__ == f"archs.{conf['arch']}"
