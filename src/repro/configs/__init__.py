@@ -21,6 +21,7 @@ _MODULES = {
     "rwkv6-3b": "repro.configs.rwkv6_3b",
     "musicgen-medium": "repro.configs.musicgen_medium",
     "chameleon-34b": "repro.configs.chameleon_34b",
+    "granite-4.0-h-micro": "repro.configs.granite_4p0_h_micro",
 }
 
 ARCH_IDS = tuple(_MODULES)

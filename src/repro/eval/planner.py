@@ -209,7 +209,8 @@ def discover_sites(cfg, params, *, batch: int = 1,
 
     leaves = _leaf_index(params)
     shared_applications = 1
-    if getattr(cfg, "family", None) == "hybrid":
+    if getattr(cfg, "family", None) == "hybrid" \
+            and getattr(cfg, "layer_types", None) is None:
         from repro.models import blocks as blocks_lib
         shared_applications = blocks_lib.hybrid_counts(cfg)[0]
 

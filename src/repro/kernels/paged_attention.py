@@ -95,7 +95,8 @@ def gather_kv(pool: jax.Array, block_table: jax.Array) -> jax.Array:
 
 def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                            block_table: jax.Array, kv_valid_len: jax.Array,
-                           *, num_heads: int) -> jax.Array:
+                           *, num_heads: int,
+                           scale: float | None = None) -> jax.Array:
     """Single-token GQA decode attention over the paged KV pool.
 
     ``q``: (B, 1, H, hd); ``kv_valid_len``: (B,) — per-request valid history
@@ -103,11 +104,12 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     valid length (page padding plus whatever the gathered pages carry beyond
     it) are masked to the same -1e30 the contiguous path uses, so the
     softmax rows match the contiguous cache bit-for-bit whenever the
-    gathered width equals the contiguous cache width.
+    gathered width equals the contiguous cache width.  ``scale``: the
+    score scale, by default ``1/sqrt(hd)``.
     """
     kc = gather_kv(pool_k, block_table)
     vc = gather_kv(pool_v, block_table)
     k_full = _repeat_kv(kc.astype(q.dtype), num_heads)
     v_full = _repeat_kv(vc.astype(q.dtype), num_heads)
     return naive_attention(q, k_full, v_full, causal=False,
-                           kv_valid_len=kv_valid_len)
+                           kv_valid_len=kv_valid_len, scale=scale)

@@ -68,6 +68,13 @@ DEFAULT_PAGES_PER_CHUNK = 8
 _MASK = -1e30  # same fill as models.attention.naive_attention
 
 
+def _scaled(scores, hd: int, scale: float | None):
+    """Scores times ``scale``; by default divided by ``sqrt(hd)``."""
+    if scale is None:
+        return scores / jnp.sqrt(jnp.float32(hd))
+    return scores * jnp.float32(scale)
+
+
 def _check_shapes(q, pool_k, pool_v, block_table, num_heads):
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(f"q must be (B, 1, H, hd), got {q.shape}")
@@ -89,7 +96,7 @@ def _check_shapes(q, pool_k, pool_v, block_table, num_heads):
 
 def _fused_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, page_size: int,
-                         num_kv_heads: int):  # analysis: allow-float-accumulation (fp32 online-softmax accumulators are the kernel's contract)
+                         num_kv_heads: int, scale: float | None):  # analysis: allow-float-accumulation (fp32 online-softmax accumulators are the kernel's contract)
     """One (request, page) grid step of the online-softmax page walk."""
     b, j = pl.program_id(0), pl.program_id(1)
     n_pages = pl.num_programs(1)
@@ -111,7 +118,7 @@ def _fused_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         h, hd = q.shape
         g = h // num_kv_heads
         qg = q.reshape(num_kv_heads, g, hd)
-        s = jnp.einsum("kgd,tkd->kgt", qg, k) / jnp.sqrt(jnp.float32(hd))
+        s = _scaled(jnp.einsum("kgd,tkd->kgt", qg, k), hd, scale)
         tok = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, page_size), 2)
         s = jnp.where(tok < valid, s, _MASK)             # (KVH, G, page)
@@ -131,9 +138,10 @@ def _fused_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("num_heads", "interpret"))
+                   static_argnames=("num_heads", "interpret", "scale"))
 def _fused_decode_pallas(q, pool_k, pool_v, block_table, kv_valid_len, *,
-                         num_heads: int, interpret: bool = False):
+                         num_heads: int, interpret: bool = False,
+                         scale: float | None = None):
     batch, _, h, hd = q.shape
     _, page_size, kvh, _ = pool_k.shape
     max_blocks = block_table.shape[1]
@@ -163,7 +171,7 @@ def _fused_decode_pallas(q, pool_k, pool_v, block_table, kv_valid_len, *,
         ],
     )
     kernel = functools.partial(_fused_decode_kernel, page_size=page_size,
-                               num_kv_heads=kvh)
+                               num_kv_heads=kvh, scale=scale)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, 1, h, hd), q.dtype),
@@ -178,7 +186,8 @@ def _fused_decode_pallas(q, pool_k, pool_v, block_table, kv_valid_len, *,
 
 def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, *,  # analysis: allow-float-accumulation (fp32 softmax, dtype schedule mirrors the gather oracle)
                       num_heads: int,
-                      pages_per_chunk: int = DEFAULT_PAGES_PER_CHUNK):
+                      pages_per_chunk: int = DEFAULT_PAGES_PER_CHUNK,
+                      scale: float | None = None):
     """K-side page walk + oracle-shaped softmax, as plain jax ops.
 
     Scores are computed page-chunk by page-chunk through the block table
@@ -221,7 +230,7 @@ def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, *,  # analys
         cols = jax.lax.dynamic_slice(bt, (0, c * ppc), (batch, ppc))
         k = pool_k[cols].astype(q.dtype).reshape(batch, t_chunk, kvh, hd)
         s = jnp.einsum("bkgd,btkd->bkgt", qg, k).astype(jnp.float32)
-        s = s / jnp.sqrt(jnp.float32(hd))
+        s = _scaled(s, hd, scale)
         tok = c * t_chunk + jnp.arange(t_chunk, dtype=jnp.int32)
         s = jnp.where(tok[None, None, None, :] < valid[:, None, None, None],
                       s, _MASK)
@@ -244,7 +253,8 @@ def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, *,  # analys
 def fused_paged_decode_attention(q, pool_k, pool_v, block_table,
                                  kv_valid_len, *, num_heads: int,
                                  impl: str = "auto", interpret: bool = False,
-                                 pages_per_chunk: int = DEFAULT_PAGES_PER_CHUNK):
+                                 pages_per_chunk: int = DEFAULT_PAGES_PER_CHUNK,
+                                 scale: float | None = None):
     """Single-token fused GQA decode attention over the paged KV pool.
 
     Drop-in for :func:`repro.kernels.paged_attention.paged_decode_attention`
@@ -257,7 +267,8 @@ def fused_paged_decode_attention(q, pool_k, pool_v, block_table,
 
     ``impl``: ``"pallas"`` (the TPU kernel; pass ``interpret=True`` on
     CPU), ``"xla"`` (the while-loop lowering), or ``"auto"`` — pallas iff
-    the default jax backend is a TPU.
+    the default jax backend is a TPU.  ``scale``: the score scale, by
+    default ``1/sqrt(hd)``.
     """
     _check_shapes(q, pool_k, pool_v, block_table, num_heads)
     if impl == "auto":
@@ -265,11 +276,11 @@ def fused_paged_decode_attention(q, pool_k, pool_v, block_table,
     if impl == "pallas":
         return _fused_decode_pallas(q, pool_k, pool_v, block_table,
                                     kv_valid_len, num_heads=num_heads,
-                                    interpret=interpret)
+                                    interpret=interpret, scale=scale)
     if impl == "xla":
         return _fused_decode_xla(q, pool_k, pool_v, block_table,
                                  kv_valid_len, num_heads=num_heads,
-                                 pages_per_chunk=pages_per_chunk)
+                                 pages_per_chunk=pages_per_chunk, scale=scale)
     raise ValueError(f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
 
 

@@ -113,11 +113,16 @@ def kv_cache_pspec(cfg: ModelConfig, rules, mesh_axes):
 # ---------------------------------------------------------------------------
 
 def naive_attention(q, k, v, *, causal: bool, q_offset=0,
-                    kv_valid_len=None) -> jax.Array:
-    """q: (B,Sq,H,D), k/v: (B,Skv,H,D) -> (B,Sq,H,Dv).  f32 softmax."""
+                    kv_valid_len=None, scale: float | None = None) -> jax.Array:
+    """q: (B,Sq,H,D), k/v: (B,Skv,H,D) -> (B,Sq,H,Dv).  f32 softmax.
+
+    ``scale``: the score scale, by default ``1/sqrt(D)``."""
     d = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(d))
+    if scale is None:
+        scores = scores / jnp.sqrt(jnp.float32(d))
+    else:
+        scores = scores * jnp.float32(scale)
     sq, sk = q.shape[1], k.shape[1]
     mask = None
     if causal:
@@ -292,8 +297,14 @@ def _gqa_fwd(params, x, cfg, *, positions, cache, cache_pos, kv_valid_len):
     q = dense(params["wq"], x, cfg, name="wq")         # (B,S,H,hd)
     k = dense(params["wk"], x, cfg, name="wk")         # (B,S,KVH,hd)
     v = dense(params["wv"], x, cfg, name="wv")
-    q = rope_lib.apply_rope(q, positions, cfg.rope_theta)
-    k = rope_lib.apply_rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = rope_lib.apply_rope(q, positions, cfg.rope_theta)
+        k = rope_lib.apply_rope(k, positions, cfg.rope_theta)
+    if cfg.attn_scale is not None:
+        # every attention below divides the scores by sqrt(hd): fold the
+        # configured scale into q (exact where the ratio is a power of two,
+        # as granite-4.0-h's 1/64 against 1/8 is)
+        q = q * jnp.asarray(cfg.attn_scale * hd ** 0.5, q.dtype)
     q = shard(q, "batch", None, "heads", "head_dim")
 
     new_cache = None

@@ -8,6 +8,11 @@ Families:
                               block applied every ``hybrid_attn_every`` layers
                               (Zamba2-style); grouped scan so the shared block
                               lowers exactly once per application site.
+  hybrid (cfg.layer_types)  : the published per-layer pattern of Mamba2 and
+                              attention mixers, each layer with weights of
+                              its own and an MLP after its mixer
+                              (granite-4.0-h); one scan per run of a kind
+                              inside a scan over the pattern's periods.
 
 All per-layer parameters are stacked with a leading ``layers`` axis and
 consumed via ``lax.scan`` — keeping HLO size (and CPU dry-run compile time)
@@ -16,8 +21,8 @@ independent of depth.  ``jax.checkpoint`` wraps the body when cfg.remat.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +40,12 @@ from repro.models.mlp import mlp_defs, mlp_fwd
 __all__ = [
     "layer_defs", "stacked_layer_defs", "shared_attn_defs",
     "stack_fwd", "init_layer_caches", "hybrid_counts",
+    "pattern_layer_defs", "layer_runs", "pattern_fwd", "CACHE_KEY",
 ]
+
+#: the caches of each kind of a layer pattern live under the key the
+#: family stacks use ("ssm", "attn")
+CACHE_KEY = {"mamba": "ssm", "attention": "attn"}
 
 
 # ---------------------------------------------------------------------------
@@ -78,10 +88,29 @@ def _stack_def(d: ParamDef, n: int) -> ParamDef:
         fan_in_axes=tuple(a + 1 for a in d.fan_in_axes))
 
 
+def pattern_layer_defs(cfg: ModelConfig) -> dict:
+    """ParamDefs for ONE layer of each kind ``cfg.layer_types`` names."""
+    def layer(mixer_key, mixer):
+        return {"ln1": ParamDef((cfg.d_model,), ("embed",), init="ones"),
+                mixer_key: mixer,
+                "ln2": ParamDef((cfg.d_model,), ("embed",), init="ones"),
+                "mlp": mlp_defs(cfg)}
+    kinds = {"mamba": lambda: layer("ssm", ssm_lib.ssm_defs(cfg)),
+             "attention": lambda: layer("attn", attn_lib.gqa_defs(cfg))}
+    return {k: make() for k, make in kinds.items() if k in cfg.layer_types}
+
+
 def stacked_layer_defs(cfg: ModelConfig, n: int | None = None) -> dict:
+    if cfg.layer_types is not None:
+        return {kind: _stacked(defs, cfg.layer_types.count(kind))
+                for kind, defs in pattern_layer_defs(cfg).items()}
     n = cfg.num_layers if n is None else n
+    return _stacked(layer_defs(cfg), n)
+
+
+def _stacked(defs: dict, n: int) -> dict:
     return jax.tree_util.tree_map(
-        lambda d: _stack_def(d, n), layer_defs(cfg),
+        lambda d: _stack_def(d, n), defs,
         is_leaf=lambda x: isinstance(x, ParamDef))
 
 
@@ -89,6 +118,31 @@ def hybrid_counts(cfg: ModelConfig) -> tuple[int, int, int]:
     """(n_groups, group_size, remainder) for the hybrid grouped scan."""
     every = cfg.hybrid_attn_every
     return cfg.num_layers // every, every, cfg.num_layers % every
+
+
+def layer_runs(cfg: ModelConfig) -> tuple[tuple[tuple[str, int, int], ...],
+                                          int]:
+    """``cfg.layer_types`` as its shortest period and the period's repeats.
+
+    The period is given as runs of one kind, ``(kind, lo, hi)``: the run
+    holds that kind's layers ``lo..hi-1`` counted within one period.
+    Granite-4.0-h's 40 layers are 4 periods of ``(mamba, 0, 5),
+    (attention, 0, 1), (mamba, 5, 9)``.
+    """
+    types = tuple(cfg.layer_types)
+    n = len(types)
+    period = next(p for p in range(1, n + 1)
+                  if n % p == 0 and types == types[:p] * (n // p))
+    runs: list[tuple[str, int, int]] = []
+    seen: dict[str, int] = {}
+    for kind in types[:period]:
+        lo = seen.get(kind, 0)
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1], lo + 1)
+        else:
+            runs.append((kind, lo, lo + 1))
+        seen[kind] = lo + 1
+    return tuple(runs), n // period
 
 
 # ---------------------------------------------------------------------------
@@ -127,14 +181,108 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return fn
 
 
+def _residual(out, cfg: ModelConfig):
+    if cfg.residual_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+    return out
+
+
+def pattern_fwd(layers: dict, x: jax.Array, cfg: ModelConfig, mixers: dict,
+                caches: dict | None = None):
+    """``x`` through the layers of ``cfg.layer_types``.
+
+    Each layer: ``x + mixer(RMSNorm(x))``, then ``x + MLP(RMSNorm(x))``,
+    both outputs scaled by ``cfg.residual_multiplier``.  ``mixers[kind](lp,
+    h, cache) -> (out, new_cache)`` runs one layer's mixer on its
+    parameters and on its slice of ``caches[CACHE_KEY[kind]]`` (None
+    without caches).  ``layers[kind]`` and the caches stack that kind's
+    layers.  One scan over the periods of ``layer_runs``, inside it one
+    scan per run over the layers' indices; each layer's weights and cache
+    slice are read out of the whole stacks by index, the caches ride in the
+    carry and each layer's slice is written back by its index, so a donated
+    cache is updated in place; a Mamba2 layer's slice is read and written
+    back under ``ssm.state_scope``, with its update.
+    Returns ``(x, new_caches)``.
+    """
+    runs, reps = layer_runs(cfg)
+    per = {kind: hi for kind, _, hi in runs}
+
+    def cache_scope(kind):
+        if kind == "mamba":
+            return ssm_lib.state_scope(x.shape[1] == 1)
+        return contextlib.nullcontext()
+
+    def take(tree, i):
+        return jax.tree_util.tree_map(
+            lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+    def layer(kind, carry, i):
+        # the layer's weights and cache slice are indexed out of the whole
+        # stacks: a scan over slices of a slice would copy the weights
+        xc, c = carry
+        key = CACHE_KEY[kind]
+        lp = take(layers[kind], i)
+        with cache_scope(kind):
+            lc = None if c is None else take(c[key], i)
+        with site_scope("layers"), site_scope(kind):
+            h = rmsnorm(lp["ln1"], xc, cfg.rms_eps)
+            out, nc = mixers[kind](lp, h, lc)
+            xc = xc + _residual(out, cfg)
+            h = rmsnorm(lp["ln2"], xc, cfg.rms_eps)
+            with site_scope("mlp"):
+                xc = xc + _residual(mlp_fwd(lp["mlp"], h, cfg), cfg)
+        if c is not None:
+            with cache_scope(kind):
+                c = {**c, key: jax.tree_util.tree_map(
+                    lambda a, n: lax.dynamic_update_index_in_dim(
+                        a, n.astype(a.dtype), i, 0), c[key], nc)}
+        return xc, c
+
+    def period(carry, p):
+        for kind, lo, hi in runs:
+            def step(carry, i, kind=kind):
+                return layer(kind, carry, i), None
+            if caches is None:
+                step = _maybe_remat(step, cfg)
+            carry, _ = lax.scan(step, carry, p * per[kind] + jnp.arange(lo, hi))
+        return carry, None
+
+    (x, caches), _ = lax.scan(period, (x, caches), jnp.arange(reps))
+    return x, caches
+
+
+def _pattern_stack_fwd(params, x, cfg, *, positions, caches, cache_pos,
+                       kv_valid_len, lengths):
+    def mamba(lp, h, cache):
+        with site_scope("ssm"):
+            return ssm_lib.ssm_fwd(lp["ssm"], h, cfg, cache=cache,
+                                   lengths=lengths)
+
+    def attention(lp, h, cache):
+        with site_scope("attn"):
+            return attn_lib.attention_fwd(
+                lp["attn"], h, cfg, positions=positions, cache=cache,
+                cache_pos=cache_pos, kv_valid_len=kv_valid_len)
+
+    x, new = pattern_fwd(params["layers"], x, cfg,
+                         {"mamba": mamba, "attention": attention}, caches)
+    return x, new, jnp.float32(0.0)
+
+
 def stack_fwd(params: dict, x: jax.Array, cfg: ModelConfig, *,
               positions, caches: dict | None = None, cache_pos=0,
-              kv_valid_len=None):
+              kv_valid_len=None, lengths=None):
     """Run the full layer stack.  Returns (x, new_caches, aux_loss).
 
     ``params`` holds "layers" (stacked) and, for hybrid, "shared" +
     "layers_tail".  ``caches`` mirrors that structure with stacked caches.
+    ``lengths`` (B,), a layer pattern's prefill only: each row's true
+    length, at which its SSM state and conv tails are taken.
     """
+    if cfg.layer_types is not None:
+        return _pattern_stack_fwd(params, x, cfg, positions=positions,
+                                  caches=caches, cache_pos=cache_pos,
+                                  kv_valid_len=kv_valid_len, lengths=lengths)
     if cfg.family == "hybrid":
         return _hybrid_fwd(params, x, cfg, positions=positions, caches=caches,
                            cache_pos=cache_pos, kv_valid_len=kv_valid_len)
@@ -255,6 +403,15 @@ def init_layer_caches(cfg: ModelConfig, batch: int, max_len: int,
         return jax.tree_util.tree_map(
             lambda a: jnp.broadcast_to(a[None], (n, *a.shape)).copy(), one)
 
+    if cfg.layer_types is not None:
+        return {
+            CACHE_KEY["mamba"]: stack(
+                lambda: ssm_lib.init_ssm_cache(cfg, batch, dtype),
+                cfg.layer_types.count("mamba")),
+            CACHE_KEY["attention"]: stack(
+                lambda: attn_lib.init_kv_cache(cfg, batch, max_len, dtype),
+                cfg.layer_types.count("attention")),
+        }
     if cfg.family == "hybrid":
         n_groups, _, _ = hybrid_counts(cfg)
         return {

@@ -84,6 +84,17 @@ class ModelConfig:
     # hybrid (zamba2-style): a shared attention+MLP block applied every
     # ``hybrid_attn_every`` SSM layers with shared weights.
     hybrid_attn_every: int = 6
+    # hybrid (granite-4.0-h-style): the published ``layer_types``, one
+    # mixer per layer ("mamba" or "attention"), each layer with weights of
+    # its own and an MLP after its mixer.  None: the family's own stack.
+    layer_types: tuple[str, ...] | None = None
+
+    # attention and residual scalings some models state
+    position_embedding: Literal["rope", "nope"] = "rope"
+    attn_scale: float | None = None       # score scale; None: 1/sqrt(hd)
+    embedding_multiplier: float = 1.0     # on the input embedding
+    residual_multiplier: float = 1.0      # on every mixer and MLP output
+    logits_scaling: float = 1.0           # the logits are divided by it
 
     # modality frontend stubs ([audio]/[vlm]): input_specs() provides
     # precomputed frame/patch embeddings of this dim instead of token ids.

@@ -37,7 +37,7 @@ def model_defs(cfg: ModelConfig) -> dict:
         "final_norm": ParamDef((cfg.d_model,), ("embed",), init="ones"),
         "layers": blocks_lib.stacked_layer_defs(cfg),
     }
-    if cfg.family == "hybrid":
+    if cfg.family == "hybrid" and cfg.layer_types is None:
         defs["shared"] = blocks_lib.shared_attn_defs(cfg)
     if not cfg.tie_embeddings:
         defs["lm_head"] = ParamDef((cfg.d_model, cfg.vocab_size),
@@ -97,6 +97,8 @@ def _embed_in(params, cfg: ModelConfig, tokens=None, embeds=None):
         x = embed_lookup(params["embed"], tokens, compute)
     if cfg.scale_embeddings:
         x = x * jnp.sqrt(jnp.float32(cfg.d_model)).astype(compute)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, compute)
     return shard(x, "batch", None, None)
 
 
@@ -118,6 +120,8 @@ def _logits_out(params, cfg: ModelConfig, x):
                 logits = jnp.matmul(x, params["lm_head"].astype(x.dtype))
         if cfg.logit_softcap is not None:
             logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return shard(logits, "batch", None, "vocab")
 
 
@@ -145,14 +149,23 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def prefill(params: dict, cfg: ModelConfig, tokens=None, *, caches,
-            embeds=None):
-    """Populate caches from a prompt.  Returns (logits, new_caches)."""
+            embeds=None, lengths=None):
+    """Populate caches from a prompt.  Returns (logits, new_caches).
+
+    ``lengths`` (B,): each row's true length in a right-padded batch.  A
+    layer pattern's SSM states and conv tails are then taken there (an
+    attention cache needs no such care: causal rows ignore the padding),
+    and the logits are each row's at its last position only, (B, 1, V).
+    """
     x = _embed_in(params, cfg, tokens, embeds)
     s = x.shape[1]
     positions = jnp.arange(s)[None, :]
     x, new_caches, _ = blocks_lib.stack_fwd(
         params, x, cfg, positions=positions, caches=caches, cache_pos=0,
-        kv_valid_len=jnp.full((x.shape[0],), s, jnp.int32))
+        kv_valid_len=jnp.full((x.shape[0],), s, jnp.int32), lengths=lengths)
+    if lengths is not None:
+        x = jnp.take_along_axis(
+            x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
     return _logits_out(params, cfg, x), new_caches
 
 

@@ -8,8 +8,11 @@ The selective state-space recurrence
 is evaluated with the chunked SSD algorithm: the sequence is split into
 chunks of length Q; intra-chunk terms become (Q, Q)-masked matmuls (MXU
 work), inter-chunk terms reduce to a short `lax.scan` over chunk states
-(B, H, N, P).  Decode keeps the (B, H, N, P) state plus a depthwise-conv tail
-buffer and costs O(1) per token — this is what makes ``long_500k`` runnable.
+(B, H, N, P).  Decode keeps the state plus a depthwise-conv tail buffer and
+costs O(1) per token — this is what makes ``long_500k`` runnable.  A cache
+holds the state as (B, H, P, N): the state size N (128 at published
+widths) minor, so a TPU lays it out without padding and without
+transposing it between a step's input and its update.
 
 Layout follows Mamba2: in_proj -> [z | x | B | C | dt], depthwise causal
 conv over the (x, B, C) channels, SSD core, gated RMSNorm, out_proj.
@@ -67,7 +70,7 @@ def init_ssm_cache(cfg: ModelConfig, batch: int, dtype=jnp.float32) -> dict:
     s, d_inner, n_heads = _dims(cfg)
     gn = s.n_groups * s.state_dim
     return {
-        "state": jnp.zeros((batch, n_heads, s.state_dim, s.head_dim), jnp.float32),
+        "state": jnp.zeros((batch, n_heads, s.head_dim, s.state_dim), jnp.float32),
         "conv_x": jnp.zeros((batch, s.conv_kernel - 1, d_inner), dtype),
         "conv_bc": jnp.zeros((batch, s.conv_kernel - 1, 2 * gn), dtype),
     }
@@ -168,8 +171,12 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, init_state=None):
 # Full Mamba2 block
 # ---------------------------------------------------------------------------
 
-def _causal_conv(seq, conv_w, conv_b, tail=None):
-    """Depthwise causal conv along seq.  seq: (B,S,C); tail: (B,K-1,C)."""
+def _causal_conv(seq, conv_w, conv_b, tail=None, lengths=None):
+    """Depthwise causal conv along seq.  seq: (B,S,C); tail: (B,K-1,C).
+
+    The new tail is each row's last K-1 inputs: those before ``lengths``
+    (B,) when given, else the sequence's last.
+    """
     k = conv_w.shape[0]
     if tail is None:
         tail = jnp.zeros((seq.shape[0], k - 1, seq.shape[2]), seq.dtype)
@@ -178,13 +185,33 @@ def _causal_conv(seq, conv_w, conv_b, tail=None):
     for i in range(k):
         out = out + full[:, i:i + seq.shape[1]] * conv_w[i].astype(seq.dtype)
     out = out + conv_b.astype(seq.dtype)
-    new_tail = full[:, full.shape[1] - (k - 1):]
+    if lengths is None:
+        new_tail = full[:, full.shape[1] - (k - 1):]
+    else:  # input t sits at full[t + k - 1]
+        new_tail = jax.vmap(lambda f, n: lax.dynamic_slice_in_dim(
+            f, n, k - 1, axis=0))(full, lengths)
     return jax.nn.silu(out), new_tail
 
 
+def state_scope(step: bool):
+    """The named scope of a Mamba2 layer's recurrence and of its state's
+    read and write-back: ``ssm_step`` for a one-token step with a cache,
+    ``ssd_scan`` for the chunked scan."""
+    return jax.named_scope("ssm_step" if step else "ssd_scan")
+
+
 def ssm_fwd(params: dict, x: jax.Array, cfg: ModelConfig, *,
-            cache: dict | None = None):
-    """x: (B, S, D) -> (out, new_cache_or_None)."""
+            cache: dict | None = None, lengths=None):
+    """x: (B, S, D) -> (out, new_cache_or_None).
+
+    ``lengths`` (B,), for a right-padded prompt: each row's true length.
+    ``dt`` is 0 past it, so the state stops changing there (decay 1, no
+    input), and the conv tails are the row's last real inputs.
+
+    The recurrence between the projections (conv and tail shift, ``dt``,
+    the SSD scan or single step, the ``D`` skip) runs under
+    ``state_scope``.
+    """
     s, d_inner, n_heads = _dims(cfg)
     gn = s.n_groups * s.state_dim
     z = shard(dense(params["w_z"], x, cfg, name="w_z"), "batch", None, "mlp")
@@ -193,39 +220,47 @@ def ssm_fwd(params: dict, x: jax.Array, cfg: ModelConfig, *,
         [dense(params["w_b"], x, cfg, name="w_b"), dense(params["w_c"], x, cfg, name="w_c")], axis=-1)
     dt = shard(dense(params["w_dt"], x, cfg, name="w_dt"), "batch", None, "heads")
 
-    tail_x = cache["conv_x"] if cache is not None else None
-    tail_bc = cache["conv_bc"] if cache is not None else None
-    xin, new_tail_x = _causal_conv(xin, params["conv_x_w"], params["conv_x_b"],
-                                   tail_x)
-    bc, new_tail_bc = _causal_conv(bc, params["conv_bc_w"], params["conv_bc_b"],
-                                   tail_bc)
-    xin = shard(xin, "batch", None, "mlp")
-    bb, cc = jnp.split(bc, [gn], axis=-1)
-
     bsz, slen = x.shape[0], x.shape[1]
-    xh = xin.reshape(bsz, slen, n_heads, s.head_dim)
-    bh = bb.reshape(bsz, slen, s.n_groups, s.state_dim)
-    ch = cc.reshape(bsz, slen, s.n_groups, s.state_dim)
-    a = -jnp.exp(params["a_log"].astype(jnp.float32))
-    dt_full = jax.nn.softplus(dt.astype(jnp.float32)
-                              + params["dt_bias"].astype(jnp.float32))
+    step = slen == 1 and cache is not None
+    with state_scope(step):
+        tail_x = cache["conv_x"] if cache is not None else None
+        tail_bc = cache["conv_bc"] if cache is not None else None
+        xin, new_tail_x = _causal_conv(xin, params["conv_x_w"],
+                                       params["conv_x_b"], tail_x, lengths)
+        bc, new_tail_bc = _causal_conv(bc, params["conv_bc_w"],
+                                       params["conv_bc_b"], tail_bc, lengths)
+        xin = shard(xin, "batch", None, "mlp")
+        bb, cc = jnp.split(bc, [gn], axis=-1)
 
-    init_state = cache["state"] if cache is not None else None
-    if slen == 1 and cache is not None:
-        # O(1) decode step.
-        rep = n_heads // s.n_groups
-        xt, dtt = xh[:, 0].astype(jnp.float32), dt_full[:, 0]
-        bt = jnp.repeat(bh[:, 0], rep, axis=1).astype(jnp.float32)
-        ct = jnp.repeat(ch[:, 0], rep, axis=1).astype(jnp.float32)
-        da = jnp.exp(dtt * a)
-        state = (init_state * da[..., None, None]
-                 + jnp.einsum("bhn,bhp->bhnp", bt, xt * dtt[..., None]))
-        yh = jnp.einsum("bhn,bhnp->bhp", ct, state)[:, None]
-        final_state = state
-    else:
-        yh, final_state = ssd_chunked(xh, dt_full, a, bh, ch, s.chunk,
-                                      init_state=init_state)
-    yh = yh + params["d_skip"].astype(yh.dtype)[None, None, :, None] * xh.astype(yh.dtype)
+        xh = xin.reshape(bsz, slen, n_heads, s.head_dim)
+        bh = bb.reshape(bsz, slen, s.n_groups, s.state_dim)
+        ch = cc.reshape(bsz, slen, s.n_groups, s.state_dim)
+        a = -jnp.exp(params["a_log"].astype(jnp.float32))
+        dt_full = jax.nn.softplus(dt.astype(jnp.float32)
+                                  + params["dt_bias"].astype(jnp.float32))
+        if lengths is not None:
+            live = jnp.arange(slen)[None, :] < lengths[:, None]
+            dt_full = jnp.where(live[..., None], dt_full, 0.0)
+
+        init_state = cache["state"] if cache is not None else None
+        if step:
+            # O(1) decode step.
+            rep = n_heads // s.n_groups
+            xt, dtt = xh[:, 0].astype(jnp.float32), dt_full[:, 0]
+            bt = jnp.repeat(bh[:, 0], rep, axis=1).astype(jnp.float32)
+            ct = jnp.repeat(ch[:, 0], rep, axis=1).astype(jnp.float32)
+            da = jnp.exp(dtt * a)
+            state = (init_state * da[..., None, None]
+                     + jnp.einsum("bhp,bhn->bhpn", xt * dtt[..., None], bt))
+            yh = jnp.einsum("bhpn,bhn->bhp", state, ct)[:, None]
+            final_state = state
+        else:
+            yh, final_state = ssd_chunked(
+                xh, dt_full, a, bh, ch, s.chunk,
+                init_state=None if init_state is None
+                else jnp.swapaxes(init_state, -1, -2))
+            final_state = jnp.swapaxes(final_state, -1, -2)
+        yh = yh + params["d_skip"].astype(yh.dtype)[None, None, :, None] * xh.astype(yh.dtype)
     y = yh.reshape(bsz, slen, d_inner).astype(x.dtype)
 
     y = rmsnorm(params["norm"], y * jax.nn.silu(z), cfg.rms_eps)

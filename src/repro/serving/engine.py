@@ -52,14 +52,16 @@ from repro.kernels import paged_attention as paged_lib
 from repro.kernels import paged_attention_fused as fused_lib
 from repro.launch.mesh import make_grid_mesh, single_device_mesh
 from repro.models import attention as attn_lib
+from repro.models import blocks as blocks_lib
 from repro.models import model as model_lib
 from repro.models import rope as rope_lib
+from repro.models import ssm as ssm_lib
 from repro.models.common import activation_scale_mode, dense, rmsnorm
 from repro.models.config import ModelConfig
 from repro.models.mlp import mlp_fwd
 from repro.serving import spans as spans_lib
 from repro.serving.energy import EnergyModel
-from repro.serving.paged_kv import PagedKVCache
+from repro.serving.paged_kv import PagedKVCache, write_slot_state
 from repro.serving.scheduler import (Request, RequestState, _SchedulerBase,
                                      make_scheduler)
 from repro.serving.traffic import TrafficRequest
@@ -332,10 +334,14 @@ class ServingEngine:
                  pricing_design: str | None = None, prompt_seed: int = 0,
                  packed: bool = False, attention: str = "fused",
                  attention_impl: str = "auto", batched_prefill: bool = True):
-        if cfg.attention != "gqa" or cfg.ssm is not None or cfg.rwkv is not None \
-                or cfg.family not in ("dense", "audio", "vlm") or cfg.is_moe:
+        hybrid = cfg.layer_types is not None
+        if cfg.attention != "gqa" or cfg.rwkv is not None or cfg.is_moe \
+                or (cfg.ssm is not None) != hybrid \
+                or cfg.family not in (("hybrid",) if hybrid
+                                      else ("dense", "audio", "vlm")):
             raise ValueError(
-                "ServingEngine supports the dense GQA transformer family "
+                "ServingEngine supports the dense GQA transformer family and "
+                "layer patterns of Mamba2 and GQA layers "
                 f"(got family={cfg.family!r}, attention={cfg.attention!r})")
         if backend is not None and plan is not None:
             raise ValueError("pass either backend= or plan=, not both")
@@ -390,7 +396,14 @@ class ServingEngine:
             self._exec_params = jax.device_put(
                 self._exec_params, _grid_shardings(self._exec_params,
                                                    self._mesh))
-        self._decode = jax.jit(self._decode_fn)
+        if hybrid:
+            # the slots' SSM states are stepped in place
+            self._decode = jax.jit(self._hybrid_decode_fn,
+                                   donate_argnums=(7,))
+            # an admitted prompt's SSM state into its slot, in place
+            self._write_state = jax.jit(write_slot_state, donate_argnums=(0,))
+        else:
+            self._decode = jax.jit(self._decode_fn)
         # an admitted prompt's K and V into its pages, the pools updated in
         # place: one program per (pool, prefill call) shape
         self._write_prompt = jax.jit(paged_lib.write_prompt_kv,
@@ -420,20 +433,9 @@ class ServingEngine:
             with site_scope("layers"):
                 h = rmsnorm(lp["ln1"], xh, cfg.rms_eps)
                 with site_scope("attn"):
-                    q = dense(lp["attn"]["wq"], h, cfg, name="wq")
-                    k = dense(lp["attn"]["wk"], h, cfg, name="wk")
-                    v = dense(lp["attn"]["wv"], h, cfg, name="wv")
-                    q = rope_lib.apply_rope(q, positions, cfg.rope_theta)
-                    k = rope_lib.apply_rope(k, positions, cfg.rope_theta)
-                    with jax.named_scope("kv_write"):
-                        pk = paged_lib.write_kv_token(
-                            pk, block_tables, lengths, k[:, 0], self.page_size)
-                        pv = paged_lib.write_kv_token(
-                            pv, block_tables, lengths, v[:, 0], self.page_size)
-                    with jax.named_scope("page_walk"):
-                        out = self._page_walk(q, pk, pv, block_tables,
-                                              lengths + 1)
-                    out = attn_lib._out_proj(lp["attn"], out, cfg)
+                    out, pk, pv = self._paged_attention(
+                        lp["attn"], h, pk, pv, block_tables, lengths,
+                        positions)
                 xh = xh + out
                 h2 = rmsnorm(lp["ln2"], xh, cfg.rms_eps)
                 with site_scope("mlp"):
@@ -450,6 +452,72 @@ class ServingEngine:
         new_lengths = jnp.where(active, lengths + 1, lengths)
         return logits, new_k, new_v, new_lengths
 
+    def _hybrid_decode_fn(self, params, tokens, k_pool, v_pool,
+                          block_tables, lengths, active, state):
+        """One ragged decode step of a layer pattern (``cfg.layer_types``).
+
+        As ``_decode_fn``, with ``blocks.pattern_fwd``'s layers: an
+        attention layer writes and walks its pages as there, a Mamba2 layer
+        steps each slot's SSM state and conv tails through
+        ``ssm.ssm_fwd``'s single-step branch.  Pools (L_attention, P, page,
+        KVH, hd); ``state`` the slots' states, leaves (L_mamba, B, ...).
+        An inactive slot's state is left zero.  Returns the logits, the
+        pools, the advanced lengths and the states.
+        """
+        cfg = self.cfg
+        x = model_lib.embed_in(params, cfg, tokens)          # (B, 1, D)
+        x = jnp.where(active[:, None, None], x, jnp.zeros((), x.dtype))
+        positions = lengths[:, None].astype(jnp.int32)
+
+        def mamba(lp, h, c):
+            with site_scope("ssm"):
+                out, new = ssm_lib.ssm_fwd(lp["ssm"], h, cfg, cache=c)
+            with ssm_lib.state_scope(True):
+                return out, jax.tree_util.tree_map(
+                    lambda a: jnp.where(
+                        active.reshape(-1, *(1,) * (a.ndim - 1)), a,
+                        jnp.zeros((), a.dtype)), new)
+
+        def attention(lp, h, c):
+            with site_scope("attn"):
+                out, pk, pv = self._paged_attention(
+                    lp["attn"], h, c["k"], c["v"], block_tables, lengths,
+                    positions)
+            return out, {"k": pk, "v": pv}
+
+        # ``ssm_step`` holds the recurrence between the projections: the
+        # state's and conv tails' read, update, readout and write-back
+        with jax.named_scope("decode_layers"):
+            x, new = blocks_lib.pattern_fwd(
+                params["layers"], x, cfg,
+                {"mamba": mamba, "attention": attention},
+                {"ssm": state, "attn": {"k": k_pool, "v": v_pool}})
+        logits = model_lib.logits_out(params, cfg, x)
+        new_lengths = jnp.where(active, lengths + 1, lengths)
+        return (logits, new["attn"]["k"], new["attn"]["v"], new_lengths,
+                new["ssm"])
+
+    def _paged_attention(self, ap, h, pk, pv, block_tables, lengths,
+                         positions):
+        """One layer's attention for the decoded rows ``h`` (B, 1, D):
+        project, write each row's K and V into its page, walk the pages.
+        Returns the output and the layer's updated pools."""
+        cfg = self.cfg
+        q = dense(ap["wq"], h, cfg, name="wq")
+        k = dense(ap["wk"], h, cfg, name="wk")
+        v = dense(ap["wv"], h, cfg, name="wv")
+        if cfg.position_embedding == "rope":
+            q = rope_lib.apply_rope(q, positions, cfg.rope_theta)
+            k = rope_lib.apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("kv_write"):
+            pk = paged_lib.write_kv_token(
+                pk, block_tables, lengths, k[:, 0], self.page_size)
+            pv = paged_lib.write_kv_token(
+                pv, block_tables, lengths, v[:, 0], self.page_size)
+        with jax.named_scope("page_walk"):
+            out = self._page_walk(q, pk, pv, block_tables, lengths + 1)
+        return attn_lib._out_proj(ap, out, cfg), pk, pv
+
     def _page_walk(self, q, pk, pv, block_tables, lengths):
         """One layer's attention of each row's query over its pages."""
         cfg = self.cfg
@@ -457,7 +525,7 @@ class ServingEngine:
             walk = functools.partial(
                 fused_lib.fused_paged_decode_attention,
                 num_heads=cfg.num_heads, impl=self.attention_impl,
-                interpret=self._fused_interpret)
+                interpret=self._fused_interpret, scale=cfg.attn_scale)
             if self._mesh.size > 1:
                 # XLA cannot partition a Mosaic kernel: on a PE-grid mesh
                 # every chip walks the whole (replicated) pools
@@ -466,7 +534,8 @@ class ServingEngine:
                                      check_vma=False)
             return walk(q, pk, pv, block_tables, lengths)
         return paged_lib.paged_decode_attention(
-            q, pk, pv, block_tables, lengths, num_heads=cfg.num_heads)
+            q, pk, pv, block_tables, lengths, num_heads=cfg.num_heads,
+            scale=cfg.attn_scale)
 
     def _prefill_cache_key(self, s: int) -> tuple:
         """Everything a compiled prefill's trace depends on, besides params.
@@ -483,29 +552,38 @@ class ServingEngine:
         return (self.cfg, self.backend, self.bits, plan_key, self.grid,
                 activation_scale_mode(), s)
 
-    def _prefill(self, tokens):
-        """(n, S) padded prompts -> (logits, stacked K, stacked V)."""
-        s = tokens.shape[1]
+    def _prefill(self, tokens, lengths=None):
+        """(n, S) padded prompts of true ``lengths`` (n,; default S) ->
+        (logits, stacked K, stacked V), and for a layer pattern the SSM
+        states and conv tails at each row's length, and the logits at that
+        length only (n, 1, V)."""
+        n, s = tokens.shape
         cfg = self.cfg
 
         def make():
-            def prefill_fn(params, toks):
+            def prefill_fn(params, toks, *lens):
                 caches = model_lib.init_caches(cfg, toks.shape[0],
                                                toks.shape[1],
                                                dtype=jnp.float32)
                 logits, new = model_lib.prefill(params, cfg, toks,
-                                                caches=caches)
-                return logits, new["attn"]["k"], new["attn"]["v"]
+                                                caches=caches,
+                                                lengths=lens[0] if lens
+                                                else None)
+                return (logits, new["attn"]["k"], new["attn"]["v"],
+                        *((new["ssm"],) if lens else ()))
 
             return jax.jit(prefill_fn)
 
         fn = _prefill_cache_get(self._prefill_cache_key(s), make)
+        if self._hybrid:
+            return fn(self._exec_params, tokens,
+                      np.full(n, s, np.int32) if lengths is None else lengths)
         return fn(self._exec_params, tokens)
 
     def _prefill_padded(self, prompts, spans=spans_lib.NULL,
                         req_ids=None) -> list[tuple]:
         """Per prompt, (last-logits row, its call's K, its call's V, its row
-        in the call).
+        in the call), and for a layer pattern its call's SSM states.
 
         The K and V are the padded call's whole outputs, (L, max_batch,
         bucket, KVH, hd).  Prompts sharing a ``_bucket(len)`` run in one
@@ -533,20 +611,51 @@ class ServingEngine:
                     for r, i in enumerate(chunk):
                         padded[r, : len(prompts[i])] = prompts[i]
                     toks = jnp.asarray(padded)
+                    # a layer pattern's states are taken at each row's length
+                    lens = (np.array([len(prompts[i]) for i in chunk]
+                                     + [0] * (self.max_batch - len(chunk)),
+                                     np.int32),) if self._hybrid else ()
                 with spans.span("prefill.call", req=reqs):
-                    logits, k_l, v_l = self._prefill(toks)
+                    logits, *call = self._prefill(toks, *lens)
                 with spans.span("prefill.slice", req=reqs):
                     for r, i in enumerate(chunk):
-                        out[i] = (logits[r, len(prompts[i]) - 1], k_l, v_l,
-                                  r)
+                        at = 0 if self._hybrid else len(prompts[i]) - 1
+                        out[i] = (logits[r, at], *call[:2], r, *call[2:])
         return out
 
     def _prefill_rows(self, prompts) -> list[tuple]:
         """Per prompt, (last-logits row, K rows, V rows) of its prefill:
         K and V rows (L, len, KVH, hd) sliced out of its padded call."""
         return [(last, k[:, r, :len(p)], v[:, r, :len(p)])
-                for p, (last, k, v, r) in zip(prompts,
-                                              self._prefill_padded(prompts))]
+                for p, (last, k, v, r, *_) in zip(
+                    prompts, self._prefill_padded(prompts))]
+
+    @property
+    def _hybrid(self) -> bool:
+        """A layer pattern: K/V pages for its attention layers only, and
+        each slot's SSM states beside them."""
+        return self.cfg.layer_types is not None
+
+    @property
+    def _kv_layers(self) -> int:
+        cfg = self.cfg
+        return (cfg.layer_types.count("attention") if self._hybrid
+                else cfg.num_layers)
+
+    def _zero_state(self):
+        """The slots' SSM states and conv tails, zero: leaves (L_mamba,
+        max_batch, ...), float32."""
+        n = self.cfg.layer_types.count("mamba")
+        one = ssm_lib.init_ssm_cache(self.cfg, self.max_batch, jnp.float32)
+        return jax.tree_util.tree_map(
+            lambda a: jnp.zeros((n, *a.shape), a.dtype), one)
+
+    @functools.cached_property
+    def _slot_state_bytes(self) -> int:
+        """Bytes of one slot's SSM states and conv tails, every layer."""
+        return sum(a.size // self.max_batch * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(
+                       jax.eval_shape(self._zero_state)))
 
     # -- host-side serving loop -----------------------------------------------
 
@@ -589,13 +698,17 @@ class ServingEngine:
             raise ValueError("scheduler.max_batch != engine max_batch")
         cfg = self.cfg
         cache = PagedKVCache(
-            num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
+            num_layers=self._kv_layers, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, num_pages=self.num_pages,
-            page_size=self.page_size, max_seq_len=self.max_seq_len)
+            page_size=self.page_size, max_seq_len=self.max_seq_len,
+            slot_state=self._zero_state() if self._hybrid else None)
         # placed as the jitted writer and decode step return them, so a
         # run's first admission reuses the programs its later ones compile
         cache.sync_pools(*jax.device_put((cache.k_pool, cache.v_pool),
                                          NamedSharding(self._mesh, P())))
+        if self._hybrid:
+            cache.sync_state(jax.device_put(cache.slot_state,
+                                            NamedSharding(self._mesh, P())))
         for req in trace:
             if req.total_len > cache.max_seq_len:
                 raise ValueError(f"request {req.req_id} needs {req.total_len} "
@@ -657,10 +770,11 @@ class ServingEngine:
             mark("evicted", req.req_id, at)
 
         def admit(req: Request, at: int, last_logits, k_call, v_call,
-                  row: int) -> None:
+                  row: int, state_call=None) -> None:
             nonlocal d_tokens, d_lengths, d_active, d_btables
             spec = req.spec
             rid = spec.req_id
+            slot = next(i for i in range(b) if slot_req[i] is None)
             cache.allocate(rid, spec.total_len)
             pages = cache.pages_needed(spec.prompt_len)
             with rec.span("kv.write_prefill", req=rid, pages=pages) as write:
@@ -673,12 +787,16 @@ class ServingEngine:
                     spec.prompt_len, ids))
                 cache.lengths[rid] = spec.prompt_len
                 write.count(dispatches=1)
+            if state_call is not None:
+                with rec.span("ssm.write_state", req=rid, slots=1,
+                              bytes=self._slot_state_bytes):
+                    cache.sync_state(self._write_state(
+                        cache.slot_state, state_call, row, slot))
             with rec.span("admit.first_token", req=rid):
                 first = int(jnp.argmax(last_logits))
             mark("first_token", rid, at)
             with rec.span("admit.tables", req=rid):
                 row = jnp.asarray(cache.block_table_row(rid), jnp.int32)
-                slot = next(i for i in range(b) if slot_req[i] is None)
                 slot_req[slot] = req
                 lengths[slot] = spec.prompt_len
                 active[slot] = True
@@ -717,10 +835,16 @@ class ServingEngine:
                     n_active = int(active.sum())
                     if n_active:
                         with rec.span("decode.dispatch", rows=n_active):
-                            logits, k_pool, v_pool, d_lengths = self._decode(
-                                self._exec_params, d_tokens, cache.k_pool,
-                                cache.v_pool, d_btables, d_lengths, d_active)
+                            state = ((cache.slot_state,) if self._hybrid
+                                     else ())
+                            logits, k_pool, v_pool, d_lengths, *state = \
+                                self._decode(self._exec_params, d_tokens,
+                                             cache.k_pool, cache.v_pool,
+                                             d_btables, d_lengths, d_active,
+                                             *state)
                             cache.sync_pools(k_pool, v_pool)
+                            if state:
+                                cache.sync_state(*state)
                             nxt_dev = jnp.argmax(logits[:, 0],
                                                  axis=-1).astype(jnp.int32)
                             d_tokens = nxt_dev[:, None]
@@ -761,6 +885,9 @@ class ServingEngine:
                         for req, row in zip(admitted, rows):
                             waiting.remove(req)
                             admit(req, step, *row)
+                        # the prefill calls' outputs go before the next
+                        # round's are made
+                        del rows, row
                     step_span.count(rows=n_active,
                                     tokens=n_active + len(admitted))
                 step += 1

@@ -11,7 +11,12 @@ Layout (vLLM-style, one logical page id spanning every layer):
   live request's pages;
 * per-request block tables (``list[int]`` of page ids, host side) padded
   with the trash page to the engine's static ``max_blocks`` width when
-  shipped to the device.
+  shipped to the device;
+* for a model with recurrent layers (a layer pattern of Mamba2 and
+  attention), the pools hold its attention layers only, and beside them
+  each decode slot's recurrent state (``slot_state``: SSM states and conv
+  tails, leaves ``(L_mamba, max_batch, ...)``), overwritten in place when
+  a request is admitted into the slot (:func:`write_slot_state`).
 
 Invariants (property-tested in ``tests/test_serving.py``):
 
@@ -27,10 +32,11 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["OutOfPages", "PageAllocator", "PagedKVCache"]
+__all__ = ["OutOfPages", "PageAllocator", "PagedKVCache", "write_slot_state"]
 
 
 class OutOfPages(RuntimeError):
@@ -98,7 +104,7 @@ class PagedKVCache:
 
     def __init__(self, *, num_layers: int, num_kv_heads: int, head_dim: int,
                  num_pages: int, page_size: int, max_seq_len: int,
-                 dtype=jnp.float32) -> None:
+                 dtype=jnp.float32, slot_state=None) -> None:
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         self.num_layers = num_layers
@@ -112,6 +118,7 @@ class PagedKVCache:
         self.allocator = PageAllocator(num_pages)
         self.block_tables: dict[object, list[int]] = {}
         self.lengths: dict[object, int] = {}
+        self.slot_state = slot_state
 
     # -- allocation ---------------------------------------------------------
 
@@ -152,18 +159,28 @@ class PagedKVCache:
         self.k_pool = k_pool
         self.v_pool = v_pool
 
+    def sync_state(self, slot_state) -> None:
+        """Adopt the slot states a jitted step or writer returned."""
+        self.slot_state = slot_state
+
     # -- host-side writes (prefill, property tests) --------------------------
 
     def write_prefill(self, req_id, k: jnp.ndarray, v: jnp.ndarray) -> None:
-        """Write a prompt's KV — ``k``/``v``: (L, S, KVH, hd) — into pages."""
-        s = int(k.shape[1])
+        """Write a prompt's KV — ``k``/``v``: (L', S, KVH, hd) — into pages.
+
+        ``L'`` may be below the pools' ``L``: the first ``L'`` layers'
+        pools are written, as when a layer pattern's attention K/V goes
+        into pools sized by its depth.
+        """
+        s, n = int(k.shape[1]), int(k.shape[0])
         pages = self.block_tables[req_id]
         ps = self.page_size
         assert s <= len(pages) * ps, "prefill longer than the reservation"
+        assert n <= self.num_layers, "more KV layers than the pools hold"
         for j in range(math.ceil(s / ps)):
             lo, hi = j * ps, min((j + 1) * ps, s)
-            self.k_pool = self.k_pool.at[:, pages[j], : hi - lo].set(k[:, lo:hi])
-            self.v_pool = self.v_pool.at[:, pages[j], : hi - lo].set(v[:, lo:hi])
+            self.k_pool = self.k_pool.at[:n, pages[j], : hi - lo].set(k[:, lo:hi])
+            self.v_pool = self.v_pool.at[:n, pages[j], : hi - lo].set(v[:, lo:hi])
         self.lengths[req_id] = s
 
     def append_token(self, req_id, k: jnp.ndarray, v: jnp.ndarray) -> None:
@@ -190,3 +207,20 @@ class PagedKVCache:
         flat = kp.reshape(kp.shape[0], -1, *kp.shape[3:])
         flatv = vp.reshape(vp.shape[0], -1, *vp.shape[3:])
         return flat[:, :n], flatv[:, :n]
+
+
+def write_slot_state(slot_state, call_state, row, slot):
+    """Row ``row`` of a prefill call's recurrent states into slot ``slot``.
+
+    Leaves ``(L, B, ...)``: the slots' states and the call's, alike but for
+    ``B``.  ``row`` and ``slot`` may be traced: a jitted writer compiles
+    once per shape, and one that donates ``slot_state`` overwrites the slot
+    in place.  Whatever the slot held before is gone, so a reused slot
+    starts from its new request's prompt alone.
+    """
+    def put(state, call):
+        new = jax.lax.dynamic_index_in_dim(call, row, axis=1)
+        return jax.lax.dynamic_update_index_in_dim(
+            state, new.astype(state.dtype), slot, axis=1)
+
+    return jax.tree_util.tree_map(put, slot_state, call_state)

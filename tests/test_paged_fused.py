@@ -214,6 +214,43 @@ def test_fused_bf16_mirrors_oracle_dtype_schedule():
     assert _diff(got, ref) <= 2 * float(jnp.finfo(jnp.bfloat16).eps)
 
 
+#: the default-scale outputs of both lowerings on one seeded case, as the
+#: kernel gave them before it took ``scale=``
+DEFAULT_SCALE_DIGESTS = {
+    "xla": "d1807bf0d7a076792cc6195cad55a52bd7ba06de08769a5fcb8bf3b68f5c12d8",
+    "pallas": "1260a149a606f2884da081e5d931d30c5a4d338e577f055182adc7645df4e6c8",
+}
+
+
+def _scale_case():
+    return _case(11, batch=3, page_size=4, kvh=2, heads=8, hd=16,
+                 max_blocks=5, lengths=[7, 20, 1])
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_fused_default_scale_is_bit_identical_to_before(impl):
+    import hashlib
+    got = fused_lib.fused_paged_decode_attention(
+        *_scale_case(), num_heads=8, impl=impl, interpret=(impl == "pallas"))
+    assert hashlib.sha256(np.asarray(got).tobytes()).hexdigest() \
+        == DEFAULT_SCALE_DIGESTS[impl]
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("scale", [0.015625, 0.3])
+def test_fused_configured_scale_matches_oracle(impl, scale):
+    """A configured score scale (granite-4.0-h's 1/64, and one that is no
+    power of two) against the gather oracle given the same scale."""
+    args = _scale_case()
+    ref = paged_decode_attention(*args, num_heads=8, scale=scale)
+    got = fused_lib.fused_paged_decode_attention(
+        *args, num_heads=8, impl=impl, interpret=(impl == "pallas"),
+        scale=scale)
+    assert _diff(got, ref) <= KERNEL_TOL
+    default = paged_decode_attention(*args, num_heads=8)
+    assert _diff(ref, default) > 100 * KERNEL_TOL
+
+
 def test_fused_rejects_bad_shapes_and_impl():
     args = _case(5, batch=2, page_size=4, kvh=2, heads=4, hd=8,
                  max_blocks=2, lengths=[3, 5])

@@ -138,3 +138,47 @@ def test_prompt_writer_updates_the_pools_in_place(one_chip):
     pool_bytes = layers * pages * page * KVH * HD * 4
     assert mem.alias_size_in_bytes == 2 * pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes
+
+
+def test_hybrid_decode_steps_the_slot_states_in_place(one_chip):
+    """granite-4.0-h-micro's decode step at its published widths, 16 slots
+    of 1792 positions: the attention layers' page walk is the Mosaic
+    kernel, and the 36 Mamba2 layers' donated SSM states and conv tails
+    alias the step's outputs with no copy of the states made."""
+    from jax.sharding import Mesh
+
+    from repro import configs
+    from repro.models import model as model_lib
+    from repro.serving.engine import ServingEngine
+
+    cfg = configs.get_config("granite-4.0-h-micro").replace(
+        param_dtype="bfloat16")
+    slots, page, blocks = 16, 16, 112
+    # the constructor walks real weights; the step reads only these
+    e = object.__new__(ServingEngine)
+    e.cfg, e.max_batch, e.page_size = cfg, slots, page
+    e.attention, e.attention_impl, e._fused_interpret = "fused", "pallas", False
+    e._mesh = Mesh(np.array([one_chip.device_set.pop()]).reshape(1, 1),
+                   ("data", "model"))
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: model_lib.init_params(cfg, jax.random.PRNGKey(0))))
+    state = place(jax.eval_shape(e._zero_state))
+    pool = _sds((4, 1 + slots * blocks, page, 8, 64), jnp.float32, one_chip)
+    with jax.set_mesh(e._mesh):
+        compiled = jax.jit(e._hybrid_decode_fn, donate_argnums=(7,)).lower(
+            params, _sds((slots, 1), jnp.int32, one_chip), pool, pool,
+            _sds((slots, blocks), jnp.int32, one_chip),
+            _sds((slots,), jnp.int32, one_chip),
+            _sds((slots,), jnp.bool_, one_chip), state).compile()
+    text = compiled.as_text()
+    state_bytes = sum(a.size * 4 for a in jax.tree_util.tree_leaves(state))
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis().alias_size_in_bytes == state_bytes
+    shape = "f32[%s]" % ",".join(map(str, state["state"].shape))
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"= {shape}" in line]
