@@ -20,7 +20,10 @@ page ids).  This module is the device-side read/write path over that layout:
   the ``kernels/flash_attention`` reference.
 
 Everything is functional (pools in, pools out) so the serving engine can jit
-one decode step over the whole layer stack with ``lax.scan``.
+one decode step over the whole layer stack with ``lax.scan``.  The token
+write and the gather also take the stacked pools ``(L, num_pages, ...)`` and
+a ``layer`` index, so that step can carry the pools whole through its scan
+and update them in place, never slicing a layer's pool out.
 """
 
 from __future__ import annotations
@@ -35,21 +38,24 @@ __all__ = ["write_kv_token", "write_prompt_kv", "gather_kv",
 
 
 def write_kv_token(pool: jax.Array, block_table: jax.Array,
-                   lengths: jax.Array, new: jax.Array,
-                   page_size: int) -> jax.Array:
+                   lengths: jax.Array, new: jax.Array, page_size: int,
+                   layer: jax.Array | None = None) -> jax.Array:
     """Scatter one new KV vector per request into its page pool.
 
-    ``pool``: (num_pages, page_size, KVH, hd); ``block_table``: (B,
-    max_blocks) int32 page ids; ``lengths``: (B,) int32 — the position the
-    new token lands at; ``new``: (B, KVH, hd).  Requests that should not
-    write (evicted slots) must point their block-table row at the reserved
-    trash page (page 0, never allocated — see ``serving.paged_kv``), which
-    absorbs their scatter without aliasing any live request's pages.
+    ``pool``: (num_pages, page_size, KVH, hd), or with ``layer`` (a scalar,
+    may be traced) the stacked (L, num_pages, page_size, KVH, hd), whose
+    layer ``layer`` takes the rows; ``block_table``: (B, max_blocks) int32
+    page ids; ``lengths``: (B,) int32 — the position the new token lands
+    at; ``new``: (B, KVH, hd).  Requests that should not write (evicted
+    slots) must point their block-table row at the reserved trash page
+    (page 0, never allocated — see ``serving.paged_kv``), which absorbs
+    their scatter without aliasing any live request's pages.
     """
     pages = jnp.take_along_axis(
         block_table, (lengths // page_size)[:, None], axis=1)[:, 0]
     slots = lengths % page_size
-    return pool.at[pages, slots].set(new.astype(pool.dtype))
+    at = (pages, slots) if layer is None else (layer, pages, slots)
+    return pool.at[at].set(new.astype(pool.dtype))
 
 
 def write_prompt_kv(k_pool: jax.Array, v_pool: jax.Array, k_call: jax.Array,
@@ -86,16 +92,21 @@ def write_prompt_kv(k_pool: jax.Array, v_pool: jax.Array, k_call: jax.Array,
     return write(k_pool, k_call), write(v_pool, v_call)
 
 
-def gather_kv(pool: jax.Array, block_table: jax.Array) -> jax.Array:
-    """(num_pages, page_size, ...) gathered to (B, max_blocks * page_size, ...)."""
+def gather_kv(pool: jax.Array, block_table: jax.Array,
+              layer: jax.Array | None = None) -> jax.Array:
+    """(num_pages, page_size, ...) gathered to (B, max_blocks * page_size, ...);
+    with ``layer``, layer ``layer`` of the stacked (L, num_pages, ...)."""
     b, max_blocks = block_table.shape
-    gathered = pool[block_table]           # (B, max_blocks, page_size, ...)
-    return gathered.reshape(b, max_blocks * pool.shape[1], *pool.shape[2:])
+    # (B, max_blocks, page_size, ...)
+    gathered = pool[block_table] if layer is None else pool[layer, block_table]
+    return gathered.reshape(b, max_blocks * gathered.shape[2],
+                            *gathered.shape[3:])
 
 
 def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                            block_table: jax.Array, kv_valid_len: jax.Array,
-                           *, num_heads: int,
+                           layer: jax.Array | None = None, *,
+                           num_heads: int,
                            scale: float | None = None) -> jax.Array:
     """Single-token GQA decode attention over the paged KV pool.
 
@@ -105,10 +116,11 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     it) are masked to the same -1e30 the contiguous path uses, so the
     softmax rows match the contiguous cache bit-for-bit whenever the
     gathered width equals the contiguous cache width.  ``scale``: the
-    score scale, by default ``1/sqrt(hd)``.
+    score scale, by default ``1/sqrt(hd)``.  ``layer``: as in
+    :func:`gather_kv`.
     """
-    kc = gather_kv(pool_k, block_table)
-    vc = gather_kv(pool_v, block_table)
+    kc = gather_kv(pool_k, block_table, layer)
+    vc = gather_kv(pool_v, block_table, layer)
     k_full = _repeat_kv(kc.astype(q.dtype), num_heads)
     v_full = _repeat_kv(vc.astype(q.dtype), num_heads)
     return naive_attention(q, k_full, v_full, causal=False,

@@ -25,8 +25,8 @@ Two interchangeable lowerings sit behind
 :func:`fused_paged_decode_attention`:
 
 * ``impl="pallas"`` — the Pallas TPU kernel (scalar-prefetched block
-  table + lengths drive the page DMA), validated under ``interpret=True``
-  on CPU like every kernel in this package;
+  table, lengths and layer drive the page DMA), validated under
+  ``interpret=True`` on CPU like every kernel in this package;
 * ``impl="xla"`` — a hybrid lowering as plain jax ops: the K/score side
   keeps the page walk (a jittable ``lax.while_loop`` over page *chunks*
   with a batch-wide dynamic early exit, so K pages past the batch's
@@ -44,6 +44,11 @@ fused outputs are NOT guaranteed bit-exact against the gather oracle —
 the contract is a gated max |Δ| (``tests/test_paged_fused.py``,
 ``repro.serving.fused_vs_gather_probe``) plus exact parity of the
 sampled token streams on seeded traffic traces.
+
+Both lowerings walk a stacked pool ``(L, P, page, KVH, hd)`` at a layer
+given as an index, so a decode step that carries the whole pools through
+its layer scan never slices a layer's pool out; a single layer's pool
+``(P, page, KVH, hd)`` is walked as a stack of one.
 
 Target: TPU v5e-class MXU; validated under ``interpret=True`` on CPU.
 """
@@ -75,13 +80,14 @@ def _scaled(scores, hd: int, scale: float | None):
     return scores * jnp.float32(scale)
 
 
-def _check_shapes(q, pool_k, pool_v, block_table, num_heads):
+def _check_shapes(q, pool_k, pool_v, block_table, num_heads, stacked):
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(f"q must be (B, 1, H, hd), got {q.shape}")
-    if pool_k.shape != pool_v.shape or pool_k.ndim != 4:
-        raise ValueError(f"pools must share (P, page, KVH, hd): "
+    if pool_k.shape != pool_v.shape or pool_k.ndim != 4 + stacked:
+        form = "(L, P, page, KVH, hd)" if stacked else "(P, page, KVH, hd)"
+        raise ValueError(f"pools must share {form}: "
                          f"{pool_k.shape} vs {pool_v.shape}")
-    kvh = pool_k.shape[2]
+    kvh = pool_k.shape[-2]
     if q.shape[2] != num_heads or num_heads % kvh:
         raise ValueError(f"num_heads {num_heads} must match q heads "
                          f"{q.shape[2]} and divide by KV heads {kvh}")
@@ -91,11 +97,12 @@ def _check_shapes(q, pool_k, pool_v, block_table, num_heads):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (B, max_blocks), block table + lengths scalar-prefetched
+# Pallas kernel: grid (B, max_blocks), block table, lengths and layer
+# scalar-prefetched
 # ---------------------------------------------------------------------------
 
-def _fused_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, page_size: int,
+def _fused_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
                          num_kv_heads: int, scale: float | None):  # analysis: allow-float-accumulation (fp32 online-softmax accumulators are the kernel's contract)
     """One (request, page) grid step of the online-softmax page walk."""
     b, j = pl.program_id(0), pl.program_id(1)
@@ -139,31 +146,32 @@ def _fused_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("num_heads", "interpret", "scale"))
-def _fused_decode_pallas(q, pool_k, pool_v, block_table, kv_valid_len, *,
-                         num_heads: int, interpret: bool = False,
+def _fused_decode_pallas(q, pool_k, pool_v, block_table, kv_valid_len, layer,
+                         *, num_heads: int, interpret: bool = False,
                          scale: float | None = None):
     batch, _, h, hd = q.shape
-    _, page_size, kvh, _ = pool_k.shape
+    _, _, page_size, kvh, _ = pool_k.shape
     max_blocks = block_table.shape[1]
     g = h // kvh
 
-    def _page_index(b, j, bt_ref, len_ref):
+    def _page_index(b, j, bt_ref, len_ref, layer_ref):
         # clamp past-the-end steps to the last live page: consecutive
         # identical block indices elide the DMA, so skipped pages cost no
         # HBM traffic (their compute is gated off by pl.when above)
         n_blocks = (len_ref[b] + page_size - 1) // page_size
-        return (bt_ref[b, jnp.minimum(j, n_blocks - 1)], 0, 0, 0)
+        return (layer_ref[0], bt_ref[b, jnp.minimum(j, n_blocks - 1)],
+                0, 0, 0)
 
+    def _row(b, j, bt_ref, len_ref, layer_ref):
+        return (b, 0, 0, 0)
+
+    # the layer's axis is squeezed: the kernel sees one page, as stored
+    page = pl.BlockSpec((pl.squeezed, 1, page_size, kvh, hd), _page_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(batch, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, h, hd), lambda b, j, bt, ln: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd), _page_index),
-            pl.BlockSpec((1, page_size, kvh, hd), _page_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, h, hd),
-                               lambda b, j, bt, ln: (b, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1, 1, h, hd), _row), page, page],
+        out_specs=pl.BlockSpec((1, 1, h, hd), _row),
         scratch_shapes=[
             pltpu.VMEM((kvh, g), jnp.float32),      # running max
             pltpu.VMEM((kvh, g), jnp.float32),      # running denominator
@@ -177,14 +185,15 @@ def _fused_decode_pallas(q, pool_k, pool_v, block_table, kv_valid_len, *,
         out_shape=jax.ShapeDtypeStruct((batch, 1, h, hd), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32),
-      jnp.asarray(kv_valid_len, jnp.int32), q, pool_k, pool_v)
+      jnp.asarray(kv_valid_len, jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pool_k, pool_v)
 
 
 # ---------------------------------------------------------------------------
 # XLA lowering: lax.while_loop over page chunks, batch-wide early exit
 # ---------------------------------------------------------------------------
 
-def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, *,  # analysis: allow-float-accumulation (fp32 softmax, dtype schedule mirrors the gather oracle)
+def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, layer, *,  # analysis: allow-float-accumulation (fp32 softmax, dtype schedule mirrors the gather oracle)
                       num_heads: int,
                       pages_per_chunk: int = DEFAULT_PAGES_PER_CHUNK,
                       scale: float | None = None):
@@ -208,7 +217,7 @@ def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, *,  # analys
     O(len·KVH) two-sided walk is the Pallas kernel's job.
     """
     batch, _, h, hd = q.shape
-    _, page_size, kvh, _ = pool_k.shape
+    _, _, page_size, kvh, _ = pool_k.shape
     max_blocks = block_table.shape[1]
     g = h // kvh
     ppc = max(1, min(pages_per_chunk, max_blocks))
@@ -228,7 +237,8 @@ def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, *,  # analys
     def body(state):
         c, scores = state
         cols = jax.lax.dynamic_slice(bt, (0, c * ppc), (batch, ppc))
-        k = pool_k[cols].astype(q.dtype).reshape(batch, t_chunk, kvh, hd)
+        k = pool_k[layer, cols].astype(q.dtype).reshape(batch, t_chunk, kvh,
+                                                        hd)
         s = jnp.einsum("bkgd,btkd->bkgt", qg, k).astype(jnp.float32)
         s = _scaled(s, hd, scale)
         tok = c * t_chunk + jnp.arange(t_chunk, dtype=jnp.int32)
@@ -241,7 +251,8 @@ def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, *,  # analys
             jnp.full((batch, kvh, g, n_chunks * t_chunk), _MASK, jnp.float32))
     _, scores = jax.lax.while_loop(cond, body, init)
     w = jax.nn.softmax(scores[..., :width], axis=-1)     # (B, KVH, G, S)
-    vc = pool_v[block_table].reshape(batch, width, kvh, hd).astype(q.dtype)
+    vc = pool_v[layer, block_table].reshape(batch, width, kvh, hd) \
+        .astype(q.dtype)
     out = jnp.einsum("bkgt,btkd->bkgd", w.astype(vc.dtype), vc)
     return out.reshape(batch, h, hd)[:, None].astype(q.dtype)
 
@@ -251,7 +262,7 @@ def _fused_decode_xla(q, pool_k, pool_v, block_table, kv_valid_len, *,  # analys
 # ---------------------------------------------------------------------------
 
 def fused_paged_decode_attention(q, pool_k, pool_v, block_table,
-                                 kv_valid_len, *, num_heads: int,
+                                 kv_valid_len, layer=None, *, num_heads: int,
                                  impl: str = "auto", interpret: bool = False,
                                  pages_per_chunk: int = DEFAULT_PAGES_PER_CHUNK,
                                  scale: float | None = None):
@@ -263,23 +274,28 @@ def fused_paged_decode_attention(q, pool_k, pool_v, block_table,
     (B, max_blocks) int32 page ids; ``kv_valid_len`` (B,) valid history
     *including* the token written this step (must be >= 1 per request —
     evicted slots point at the trash page with length 0, so the engine
-    passes ``lengths + 1``).
+    passes ``lengths + 1``).  With ``layer`` (a scalar, may be traced) the
+    pools are stacked, (L, P, page_size, KVH, hd), and the walk reads
+    layer ``layer``'s pages in place; without it a pool is one layer's.
 
     ``impl``: ``"pallas"`` (the TPU kernel; pass ``interpret=True`` on
     CPU), ``"xla"`` (the while-loop lowering), or ``"auto"`` — pallas iff
     the default jax backend is a TPU.  ``scale``: the score scale, by
     default ``1/sqrt(hd)``.
     """
-    _check_shapes(q, pool_k, pool_v, block_table, num_heads)
+    _check_shapes(q, pool_k, pool_v, block_table, num_heads,
+                  stacked=layer is not None)
+    if layer is None:  # one layer's pools: a stack of one
+        pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "pallas":
         return _fused_decode_pallas(q, pool_k, pool_v, block_table,
-                                    kv_valid_len, num_heads=num_heads,
+                                    kv_valid_len, layer, num_heads=num_heads,
                                     interpret=interpret, scale=scale)
     if impl == "xla":
         return _fused_decode_xla(q, pool_k, pool_v, block_table,
-                                 kv_valid_len, num_heads=num_heads,
+                                 kv_valid_len, layer, num_heads=num_heads,
                                  pages_per_chunk=pages_per_chunk, scale=scale)
     raise ValueError(f"impl must be 'pallas', 'xla' or 'auto', got {impl!r}")
 

@@ -13,7 +13,10 @@ step that advances *every* slot one token per scheduler step:
 * **decode** (every step): the jitted step embeds each slot's pending
   token at its own position, scatters the new K/V into its pages
   (``kernels.paged_attention.write_kv_token``), attends over the gathered
-  pages, and emits next-token logits.  The step mirrors
+  pages, and emits next-token logits.  It carries the whole stacked pools
+  through its layer scan, donated, and writes and walks them by layer
+  index, so they are updated in place: a caller must not reuse a pool it
+  passed, only the pools the step returns.  The step mirrors
   ``models.blocks._transformer_block`` op for op — same ``dense`` sites
   under the same ``site_scope`` names (``layers/attn/wq`` …, ``lm_head``)
   — so ``use_backend(...)``/``use_plan(...)`` scopes contract every token
@@ -312,8 +315,9 @@ def fused_vs_gather_probe(cfg, params, *, batch: int = 2, prompt_len: int = 5,
             pos = prompt_len + i
             args = (jnp.asarray(btables), jnp.full((batch,), pos, jnp.int32),
                     jnp.ones((batch,), bool))
-            lg_f, _, _, _ = fused._decode(params, tok, cache.k_pool,
-                                          cache.v_pool, *args)
+            # each step donates its pools: the fused one takes copies
+            lg_f, _, _, _ = fused._decode(params, tok, jnp.copy(cache.k_pool),
+                                          jnp.copy(cache.v_pool), *args)
             lg_g, k_pool, v_pool, _ = gather._decode(params, tok,
                                                      cache.k_pool,
                                                      cache.v_pool, *args)
@@ -403,7 +407,8 @@ class ServingEngine:
             # an admitted prompt's SSM state into its slot, in place
             self._write_state = jax.jit(write_slot_state, donate_argnums=(0,))
         else:
-            self._decode = jax.jit(self._decode_fn)
+            # the pools are carried through the layer scan in place
+            self._decode = jax.jit(self._decode_fn, donate_argnums=(2, 3))
         # an admitted prompt's K and V into its pages, the pools updated in
         # place: one program per (pool, prefill call) shape
         self._write_prompt = jax.jit(paged_lib.write_prompt_kv,
@@ -420,7 +425,10 @@ class ServingEngine:
         for the incoming token; active (B,) bool.  Mirrors
         ``blocks._transformer_block`` exactly (sites, scopes, op order) with
         the contiguous ``dynamic_update_slice`` cache swapped for the paged
-        scatter/gather path.
+        scatter/gather path.  The pools are carried whole through the layer
+        scan, and each layer writes and walks them at its index, so no
+        layer's pool is sliced out or stacked back; ``ServingEngine``
+        donates them, and the step updates them in place.
         """
         cfg = self.cfg
         x = model_lib.embed_in(params, cfg, tokens)          # (B, 1, D)
@@ -428,29 +436,30 @@ class ServingEngine:
         positions = lengths[:, None].astype(jnp.int32)
 
         def body(carry, xs):
-            xh = carry
-            lp, pk, pv = xs
+            xh, pk, pv = carry
+            lp, layer = xs
             with site_scope("layers"):
                 h = rmsnorm(lp["ln1"], xh, cfg.rms_eps)
                 with site_scope("attn"):
                     out, pk, pv = self._paged_attention(
                         lp["attn"], h, pk, pv, block_tables, lengths,
-                        positions)
+                        positions, layer)
                 xh = xh + out
                 h2 = rmsnorm(lp["ln2"], xh, cfg.rms_eps)
                 with site_scope("mlp"):
                     xh = xh + mlp_fwd(lp["mlp"], h2, cfg)
-            return xh, (pk, pv)
+            return (xh, pk, pv), None
 
         # ``decode_layers``, ``kv_write`` and ``page_walk`` name the step's
         # parts in the HLO's op_name metadata, beside the site scopes
         with jax.named_scope("decode_layers"):
-            x, (new_k, new_v) = jax.lax.scan(
-                body, x, (params["layers"], k_pool, v_pool))
+            (x, k_pool, v_pool), _ = jax.lax.scan(
+                body, (x, k_pool, v_pool),
+                (params["layers"], jnp.arange(k_pool.shape[0])))
         logits = model_lib.logits_out(params, cfg, x)
         # lengths advance on-device so the host never re-uploads them
         new_lengths = jnp.where(active, lengths + 1, lengths)
-        return logits, new_k, new_v, new_lengths
+        return logits, k_pool, v_pool, new_lengths
 
     def _hybrid_decode_fn(self, params, tokens, k_pool, v_pool,
                           block_tables, lengths, active, state):
@@ -498,10 +507,12 @@ class ServingEngine:
                 new["ssm"])
 
     def _paged_attention(self, ap, h, pk, pv, block_tables, lengths,
-                         positions):
+                         positions, layer=None):
         """One layer's attention for the decoded rows ``h`` (B, 1, D):
         project, write each row's K and V into its page, walk the pages.
-        Returns the output and the layer's updated pools."""
+        Pools are the layer's own, or with ``layer`` the stacked pools,
+        written and walked at that index.  Returns the output and the
+        updated pools."""
         cfg = self.cfg
         q = dense(ap["wq"], h, cfg, name="wq")
         k = dense(ap["wk"], h, cfg, name="wk")
@@ -511,14 +522,14 @@ class ServingEngine:
             k = rope_lib.apply_rope(k, positions, cfg.rope_theta)
         with jax.named_scope("kv_write"):
             pk = paged_lib.write_kv_token(
-                pk, block_tables, lengths, k[:, 0], self.page_size)
+                pk, block_tables, lengths, k[:, 0], self.page_size, layer)
             pv = paged_lib.write_kv_token(
-                pv, block_tables, lengths, v[:, 0], self.page_size)
+                pv, block_tables, lengths, v[:, 0], self.page_size, layer)
         with jax.named_scope("page_walk"):
-            out = self._page_walk(q, pk, pv, block_tables, lengths + 1)
+            out = self._page_walk(q, pk, pv, block_tables, lengths + 1, layer)
         return attn_lib._out_proj(ap, out, cfg), pk, pv
 
-    def _page_walk(self, q, pk, pv, block_tables, lengths):
+    def _page_walk(self, q, pk, pv, block_tables, lengths, layer=None):
         """One layer's attention of each row's query over its pages."""
         cfg = self.cfg
         if self.attention == "fused":
@@ -530,11 +541,11 @@ class ServingEngine:
                 # XLA cannot partition a Mosaic kernel: on a PE-grid mesh
                 # every chip walks the whole (replicated) pools
                 walk = jax.shard_map(walk, mesh=self._mesh,
-                                     in_specs=(P(),) * 5, out_specs=P(),
+                                     in_specs=(P(),) * 6, out_specs=P(),
                                      check_vma=False)
-            return walk(q, pk, pv, block_tables, lengths)
+            return walk(q, pk, pv, block_tables, lengths, layer)
         return paged_lib.paged_decode_attention(
-            q, pk, pv, block_tables, lengths, num_heads=cfg.num_heads,
+            q, pk, pv, block_tables, lengths, layer, num_heads=cfg.num_heads,
             scale=cfg.attn_scale)
 
     def _prefill_cache_key(self, s: int) -> tuple:

@@ -28,6 +28,7 @@ argmax flips.  This module holds that claim differentially:
 """
 
 import dataclasses
+import functools
 import os
 import subprocess
 import sys
@@ -251,6 +252,53 @@ def test_fused_configured_scale_matches_oracle(impl, scale):
     assert _diff(ref, default) > 100 * KERNEL_TOL
 
 
+@pytest.mark.parametrize("impl", ["gather", "xla", "pallas"])
+def test_layer_indexed_write_and_walk_match_the_layer_slice(impl):
+    """For every layer of a stacked pool, the token write and the page walk
+    at a (traced) layer index equal the single-layer form on that layer's
+    pool, bit for bit; the write leaves every other layer as it was."""
+    from repro.kernels.paged_attention import write_kv_token
+    layers, page_size, kvh, heads, hd = 3, 4, 2, 4, 8
+    q, _, _, bt, pos = _case(21, batch=3, page_size=page_size, kvh=kvh,
+                             heads=heads, hd=hd, max_blocks=4,
+                             lengths=[0, 5, 11])
+    keys = jax.random.split(jax.random.PRNGKey(22), 4)
+    shape = (layers, 1 + 3 * 4, page_size, kvh, hd)
+    pool_k = jax.random.normal(keys[0], shape)
+    pool_v = jax.random.normal(keys[1], shape)
+    new_k = jax.random.normal(keys[2], (3, kvh, hd))
+    new_v = jax.random.normal(keys[3], (3, kvh, hd))
+    if impl == "gather":
+        walk = functools.partial(paged_decode_attention, num_heads=heads)
+    else:
+        walk = functools.partial(fused_lib.fused_paged_decode_attention,
+                                 num_heads=heads, impl=impl,
+                                 interpret=(impl == "pallas"))
+
+    @jax.jit
+    def stacked(layer):
+        pk = write_kv_token(pool_k, bt, pos, new_k, page_size, layer)
+        pv = write_kv_token(pool_v, bt, pos, new_v, page_size, layer)
+        return pk, pv, walk(q, pk, pv, bt, pos + 1, layer)
+
+    @jax.jit
+    def one(pk, pv):
+        pk = write_kv_token(pk, bt, pos, new_k, page_size)
+        pv = write_kv_token(pv, bt, pos, new_v, page_size)
+        return pk, pv, walk(q, pk, pv, bt, pos + 1)
+
+    for layer in range(layers):
+        pk, pv, got = stacked(jnp.int32(layer))
+        ref_k, ref_v, ref = one(pool_k[layer], pool_v[layer])
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+        for new, ref_pool, pool in ((pk, ref_k, pool_k), (pv, ref_v, pool_v)):
+            np.testing.assert_array_equal(np.asarray(new[layer]),
+                                          np.asarray(ref_pool))
+            rest = np.array([i for i in range(layers) if i != layer])
+            np.testing.assert_array_equal(np.asarray(new[rest]),
+                                          np.asarray(pool[rest]))
+
+
 def test_fused_rejects_bad_shapes_and_impl():
     args = _case(5, batch=2, page_size=4, kvh=2, heads=4, hd=8,
                  max_blocks=2, lengths=[3, 5])
@@ -262,6 +310,8 @@ def test_fused_rejects_bad_shapes_and_impl():
     q_bad = jnp.zeros((2, 2, 4, 8))
     with pytest.raises(ValueError, match="B, 1, H"):
         fused_lib.fused_paged_decode_attention(q_bad, *args[1:], num_heads=4)
+    with pytest.raises(ValueError, match="L, P, page"):  # a layer, 4-D pools
+        fused_lib.fused_paged_decode_attention(*args, 0, num_heads=4)
 
 
 # ---------------------------------------------------------------------------
@@ -322,6 +372,15 @@ def test_engine_fused_vs_gather_streams_per_row_quantized(cfg, params):
 
 def test_fused_vs_gather_probe_within_tol(cfg, params):
     assert fused_vs_gather_probe(cfg, params) <= FUSED_LOGIT_TOL
+
+
+def test_fused_vs_gather_probe_with_donated_pools(cfg, params):
+    """Both engines' decode steps donate their pools (a donated pool read
+    again raises): the probe hands the fused step copies and walks on from
+    the gather step's pools, here at a page size that splits the prompt and
+    for more steps than its default."""
+    assert fused_vs_gather_probe(cfg, params, page_size=3,
+                                 steps=5) <= FUSED_LOGIT_TOL
 
 
 def test_fused_vs_gather_probe_pallas_interpret(cfg, params):

@@ -446,6 +446,45 @@ class TestEngineParity:
         assert rep.request_tokens[0] == tuple(int(t) for t in
                                               np.asarray(ref)[0])
 
+    def test_ragged_requests_match_generate(self, cfg, params):
+        """Requests of ragged lengths that come and go through the paged
+        engine, its decode step updating the donated pools in place across
+        admissions and evictions, each emit exactly the greedy tokens
+        ``launch.serve.generate`` produces for their prompt alone; and the
+        paged decode step equals the contiguous one bit for bit."""
+        trace = generate_trace(_tcfg(seed=3, n=6, rate=2.0))
+        eng = _engine(cfg, params)
+        rep = eng.run(trace, "continuous")
+        assert rep.requests == len(trace)
+        for spec in trace:
+            prompt = jnp.asarray(eng.prompt_tokens(spec)[None])
+            ref = serve_lib.generate(cfg, params, single_device_mesh(),
+                                     prompt, spec.output_len)
+            assert rep.request_tokens[spec.req_id] == tuple(
+                int(t) for t in np.asarray(ref)[0]), spec
+        assert paged_vs_contiguous_probe(cfg, params, batch=3).decode == 0.0
+
+    def test_decode_step_donates_its_pools(self, cfg, params):
+        """The dense decode step consumes the pools it is passed: they are
+        deleted, and only the returned pools may be used again."""
+        eng = _engine(cfg, params)
+        cache = PagedKVCache(num_layers=cfg.num_layers,
+                             num_kv_heads=cfg.num_kv_heads,
+                             head_dim=cfg.resolved_head_dim,
+                             num_pages=eng.num_pages,
+                             page_size=eng.page_size,
+                             max_seq_len=eng.max_seq_len)
+        b = eng.max_batch
+        k_pool, v_pool = cache.k_pool, cache.v_pool
+        with jax.set_mesh(eng._mesh):
+            _, new_k, new_v, _ = eng._decode(
+                eng._exec_params, jnp.zeros((b, 1), jnp.int32), k_pool,
+                v_pool, jnp.zeros((b, cache.max_blocks), jnp.int32),
+                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
+        assert k_pool.is_deleted() and v_pool.is_deleted()
+        assert new_k.shape == new_v.shape == k_pool.shape
+        assert not (new_k.is_deleted() or new_v.is_deleted())
+
     def test_backend_execution_flat_vs_1x1_grid(self, cfg, params):
         """Under tubgemm execution, a (1,1) PE-array grid serves the trace
         with exactly the flat backend's tokens and metrics (GridBackend is

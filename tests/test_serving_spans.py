@@ -232,6 +232,9 @@ def test_decode_program_carries_its_scopes(served, cfg):
                   "layers/mlp/w_up/", "layers/attn/kv_write/",
                   "layers/attn/page_walk/", "lm_head/"):
         assert any(scope in n for n in names), scope
-    # the scan's own slicing of each layer's pools lies under no site scope
-    assert any("decode_layers/" in n and "/layers/" not in n
-               and "dynamic_update_slice" in n for n in names)
+    # the pools are carried whole through the layer scan: under no site
+    # scope is a layer's pool stacked back, and no op makes one layer's pool
+    assert not any("decode_layers/" in n and "/layers/" not in n
+                   and "dynamic_update_slice" in n for n in names)
+    layer_pool = "= f32[%s]" % ",".join(map(str, cache.k_pool.shape[1:]))
+    assert layer_pool not in text

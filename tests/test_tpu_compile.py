@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -182,3 +183,45 @@ def test_hybrid_decode_steps_the_slot_states_in_place(one_chip):
     shape = "f32[%s]" % ",".join(map(str, state["state"].shape))
     assert not [line for line in text.splitlines()
                 if " copy(" in line and f"= {shape}" in line]
+
+
+def test_dense_decode_updates_the_pools_in_place(one_chip):
+    """phi3-mini-3.8b's decode step at its published widths, bf16, 4 slots
+    of 512 positions (32 layers, 129 pages of 16, 32 KV heads of 96): the
+    page walk is the Mosaic kernel, both donated pools alias the step's
+    outputs, and no op slices one layer's pool out or stacks it back."""
+    from repro import configs
+    from repro.models import model as model_lib
+    from repro.serving.engine import ServingEngine
+
+    cfg = configs.get_config("phi3-mini-3.8b").replace(
+        param_dtype="bfloat16", compute_dtype="bfloat16")
+    slots, page, blocks = 4, 16, 32
+    e = object.__new__(ServingEngine)
+    e.cfg, e.max_batch, e.page_size = cfg, slots, page
+    e.attention, e.attention_impl, e._fused_interpret = "fused", "pallas", False
+    e._mesh = Mesh(np.array([one_chip.device_set.pop()]).reshape(1, 1),
+                   ("data", "model"))
+    params = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(
+            lambda: model_lib.init_params(cfg, jax.random.PRNGKey(0))))
+    layer = (1 + slots * blocks, page, cfg.num_kv_heads, cfg.resolved_head_dim)
+    pool = _sds((cfg.num_layers, *layer), jnp.float32, one_chip)
+    with jax.set_mesh(e._mesh):
+        compiled = jax.jit(e._decode_fn, donate_argnums=(2, 3)).lower(
+            params, _sds((slots, 1), jnp.int32, one_chip), pool, pool,
+            _sds((slots, blocks), jnp.int32, one_chip),
+            _sds((slots,), jnp.int32, one_chip),
+            _sds((slots,), jnp.bool_, one_chip)).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    # as laid out: (8, 128) tiles pad the head dim 96 to 128
+    laid_out = cfg.num_layers * np.prod(layer[:-1]) * 128 * 4
+    layer_bytes = np.prod(layer) * 4
+    assert "tpu_custom_call" in text
+    assert mem.alias_size_in_bytes == 2 * laid_out
+    assert mem.temp_size_in_bytes < layer_bytes
+    shape = "= f32[%s]" % ",".join(map(str, layer))
+    assert not [line for line in text.splitlines() if shape in line
+                and re.search(r"copy|dynamic-(update-)?slice", line)]
